@@ -7,7 +7,9 @@ can be (re)generated with:
         --out dryrun_tuned_both.json
 
 The merge row times the compiled ppermute tree reduction
-(collectives.topk_merge_axis) at S=8 on fake CPU devices and derives
+(collectives.topk_merge_axis) at S=8 on fake CPU devices, in a child
+process pinned to ``JAX_PLATFORMS=cpu`` (a CPU simulation, labelled
+``platform=cpu-sim``; a failed child fails the suite) and derives
 the wire traffic per round — ceil(log2 S) rounds of B*k*(4+4) bytes per
 shard (f32 dist + i32 id; bf16 wire halves the dist half) — against the
 achieved effective bandwidth, with the host-python merge the tree
@@ -28,7 +30,7 @@ _MERGE_CHILD = """
     import json, time
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.sharded import SHARD_AXIS, shard_mesh
     from repro.distributed.collectives import hierarchical_topk
@@ -40,7 +42,7 @@ _MERGE_CHILD = """
                                        tie_break_ids=True, axis_sizes=(s,)),
         mesh=mesh,
         in_specs=(P(SHARD_AXIS, None, None), P(SHARD_AXIS, None, None)),
-        out_specs=(P(None, None), P(None, None)), check_rep=False))
+        out_specs=(P(None, None), P(None, None)), check_vma=False))
     rng = np.random.default_rng(0)
     d = np.sort(rng.random((s, b, k)).astype(np.float32), -1)
     i = rng.permutation(s * b * k).astype(np.int32).reshape(s, b, k)
@@ -101,6 +103,9 @@ def run(rows: list):
 def _merge_row(rows: list):
     """Measured cross-shard merge roofline row (see module docstring)."""
     env = dict(os.environ)
+    # a CPU simulation: the child never reaches for the chip, which the
+    # parent process already holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = (os.path.join(ROOT, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
@@ -108,9 +113,8 @@ def _merge_row(rows: list):
         [sys.executable, "-c", textwrap.dedent(_MERGE_CHILD)],
         capture_output=True, text=True, env=env, timeout=600)
     if proc.returncode != 0:
-        rows.append(("roofline_merge_S8", 0,
-                     f"FAILED:{proc.stderr[-200:]}"))
-        return
+        raise RuntimeError(
+            f"roofline merge child failed: {proc.stderr[-2000:]}")
     payload = next(line for line in proc.stdout.splitlines()
                    if line.startswith("ROW"))
     r = json.loads(payload[len("ROW"):])
@@ -121,7 +125,7 @@ def _merge_row(rows: list):
              else "dispatch(cpu-sim)")
     rows.append((
         f"roofline_merge_S{r['s']}", r["t_tree_us"],
-        f"rounds={r['rounds']},wire_kb_round={r['wire_kb_round']:.0f},"
+        f"platform=cpu-sim,rounds={r['rounds']},wire_kb_round={r['wire_kb_round']:.0f},"
         f"allgather_kb={r['allgather_kb']:.0f},"
         f"achieved_gbps={r['gbps']:.2f},host_merge_us={r['t_host_us']:.0f},"
         f"bound={bound}"))

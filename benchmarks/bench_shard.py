@@ -33,8 +33,11 @@ shard_map tree (ppermute rounds included) and the per-round term is the
 identical pairwise keep-k kernel on one device.
 
 The sharded path needs a multi-device mesh, so this suite spawns ONE
-subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-set before jax imports (the same idiom as tests/test_distributed.py) and
+subprocess with ``JAX_PLATFORMS=cpu`` and
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` set before jax
+imports — a CPU simulation on every host, whose rows say
+``platform=cpu-sim``; the child never contends for a chip the parent
+holds (the same idiom as tests/test_distributed.py) and
 sweeps shard counts inside it — each S builds its mesh over the first S
 fake devices. Smoke mode shrinks N for CI; the full run measures the
 acceptance shape (N=100k, S in 1..8).
@@ -52,7 +55,7 @@ _CHILD = """
     import json, time
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import make_index
     from repro.core.sharded import SHARD_AXIS, shard_mesh
@@ -88,7 +91,7 @@ _CHILD = """
                                            axis_sizes=(s,)),
             mesh=mesh,
             in_specs=(P(SHARD_AXIS, None, None), P(SHARD_AXIS, None, None)),
-            out_specs=(P(None, None), P(None, None)), check_rep=False)
+            out_specs=(P(None, None), P(None, None)), check_vma=False)
         return jax.jit(f), mesh
 
     out = []
@@ -178,6 +181,9 @@ def run(rows: list):
         ns=ns, shard_counts=shard_counts, dim=dim, b=b, k=k, reps=reps,
         hnsw_n=hnsw_n))
     env = dict(os.environ)
+    # a CPU simulation: the child never reaches for the chip, which the
+    # parent process already holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -189,6 +195,7 @@ def run(rows: list):
     for r in json.loads(payload[len("ROWS"):]):
         if r["row"] == "flat":
             rows.append((f"shard_S{r['s']}_n{r['n']}", r["us"],
+                         f"platform=cpu-sim,"
                          f"speedup={r['speedup']:.2f}x,"
                          f"merge_us={r['merge_us']:.0f},"
                          f"host_wall_us={r['wall_us']:.0f},"
@@ -196,6 +203,7 @@ def run(rows: list):
                          f"capacity_headroom={r['s']}x"))
         else:
             rows.append((f"shard_hnsw_S{r['s']}_n{r['n']}", r["us"],
+                         f"platform=cpu-sim,"
                          f"loop_us={r['loop_us']:.0f},"
                          f"speedup_vs_loop={r['speedup_vs_loop']:.2f}x,"
                          f"dispatches=1"))

@@ -29,6 +29,8 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as JSON (see benchmarks/README.md)")
     args = ap.parse_args()
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     only = set(args.only.split(",")) if args.only else set(SUITES)
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"

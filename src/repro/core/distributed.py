@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.distributed.collectives import hierarchical_topk
 from repro.kernels import ops
@@ -77,7 +77,7 @@ def sharded_flat_topk(mesh: Mesh, db: jax.Array, queries: jax.Array, k: int,
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(axes, None), P(None, None)),
                    out_specs=(P(None, None), P(None, None)),
-                   check_rep=False)   # post-merge values ARE replicated
+                   check_vma=False)   # post-merge values ARE replicated
     return fn(db, queries)
 
 

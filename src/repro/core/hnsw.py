@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import dispatch
 from repro.core.hnsw_build import HNSWGraph
 from repro.distributed.sharding import shard
+from repro.kernels.layout import PackedRows, device_capacity, pack_rows
 
 INF = jnp.float32(3.0e38)
 
@@ -51,10 +52,20 @@ class DeviceGraph:
     carrying the int8 per-row decode scales. Every distance decodes in
     fp32 (fused into the gather kernel), so HBM holds the small encoding
     while the math stays asymmetric fp32.
+
+    The graph owns the layout its kernels read (kernels/layout.py): N is
+    the host capacity rounded up by ``device_capacity`` (a multiple of
+    1024 rows; the extra rows are zero, edgeless and tombstoned), and the
+    adjacency tables are stored packed into 128-lane rows. So the beam
+    and gather kernels view ``vectors``, ``scales`` and ``neighbors0``
+    without copying them, the greedy descent gathers upper-layer rows
+    without re-laying out a layer (an [N, M] slice would sit lane-padded
+    to 128 lanes), and dirty rows scatter straight into the packed form.
     """
     vectors: jax.Array      # [N, D] storage dtype (normalised if cosine)
-    neighbors0: jax.Array   # [N, 2M] int32 (-1 pad)
-    upper: jax.Array        # [L, N, M] int32 (-1 pad); L may be 0
+    neighbors0: PackedRows  # [N, 2M] int32 (-1 pad), packed [N/R, 128]
+    upper: PackedRows       # [L·N, M] int32 (-1 pad): layer l >= 1, node
+                            # i at row (l-1)·N + i; L may be 0
     levels: jax.Array       # [N] int32
     entry: jax.Array        # scalar int32
     deleted: jax.Array      # [N] bool tombstones
@@ -78,16 +89,46 @@ class DeviceGraph:
     def n(self) -> int:
         return self.vectors.shape[0]
 
+    @property
+    def n_layers(self) -> int:
+        """Upper layers held (L)."""
+        return self.upper.rows // self.n
+
+    def fits(self, g: HNSWGraph) -> bool:
+        """True when this graph is the device layout of host graph ``g``'s
+        capacity view, so dirty rows can scatter into it."""
+        return (self.n == device_capacity(g.vectors.shape[0])
+                and self.vectors.shape[1] == g.vectors.shape[1]
+                and self.n_layers == g.upper.shape[0])
+
+
+def _pad_rows(x: np.ndarray, cap: int, fill, axis: int = 0) -> np.ndarray:
+    """Host array padded with ``fill`` to ``cap`` entries along ``axis``."""
+    pad = cap - x.shape[axis]
+    if pad <= 0:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return np.pad(x, widths, constant_values=fill)
+
+
+def _device_deleted(deleted: np.ndarray, n: int, cap: int) -> jax.Array:
+    """Tombstones for the first ``n`` rows; rows past them are dead."""
+    return jnp.asarray(_pad_rows(np.asarray(deleted[:n], bool), cap, True))
+
 
 def to_device_graph(g: HNSWGraph, deleted: np.ndarray | None = None,
                     enc: np.ndarray | None = None,
                     scales: np.ndarray | None = None) -> DeviceGraph:
     """Full host->device conversion (the from-scratch path; incremental
-    updates go through :func:`apply_row_updates`).
+    updates go through :func:`apply_row_updates`). The host arrays are
+    padded to ``device_capacity`` rows and the adjacency packed here, on
+    the host, once.
 
     ``enc``/``scales``: codec-encoded rows to upload INSTEAD of the host
     f32 vectors (same [N, D] capacity view, DESIGN.md §9)."""
     n = g.vectors.shape[0]
+    cap = device_capacity(n)
     if deleted is None:
         deleted = np.zeros(n, bool)
     v = g.vectors if enc is None else enc
@@ -97,18 +138,33 @@ def to_device_graph(g: HNSWGraph, deleted: np.ndarray | None = None,
                        + 4 * g.upper.shape[0] * (g.upper.shape[2]
                                                  if g.upper.shape[0] else 0)
                        + 4 + (4 if scales is not None else 0)))
+    nbr = pack_rows(_pad_rows(np.asarray(g.neighbors0, np.int32), cap, -1))
+    up = _pad_rows(np.asarray(g.upper, np.int32), cap, -1, axis=1)
+    up = pack_rows(up.reshape(-1, up.shape[2]))
     return DeviceGraph(
-        vectors=(jnp.asarray(g.vectors, jnp.float32) if enc is None
-                 else jnp.asarray(enc)),
-        neighbors0=jnp.asarray(g.neighbors0, jnp.int32),
-        upper=jnp.asarray(g.upper, jnp.int32),
-        levels=jnp.asarray(g.levels, jnp.int32),
+        vectors=jnp.asarray(_pad_rows(
+            np.asarray(g.vectors, np.float32) if enc is None else enc,
+            cap, 0)),
+        neighbors0=PackedRows(jnp.asarray(nbr.table), nbr.width),
+        upper=PackedRows(jnp.asarray(up.table), up.width),
+        levels=jnp.asarray(_pad_rows(np.asarray(g.levels, np.int32), cap, 0)),
         entry=jnp.asarray(max(g.entry, 0), jnp.int32),
-        deleted=jnp.asarray(deleted[:n], bool),
+        deleted=_device_deleted(deleted, n, cap),
         max_level=int(g.max_level),
         metric=g.metric,
-        scales=None if scales is None else jnp.asarray(scales, jnp.float32),
+        scales=(None if scales is None else jnp.asarray(
+            _pad_rows(np.asarray(scales, np.float32), cap, 0))),
     )
+
+
+def _set_upper(upper: PackedRows, n: int, rows, u_new) -> PackedRows:
+    """Rows ``rows`` of every upper layer set to ``u_new`` [L, K, M]."""
+    layers = upper.rows // n
+    if not layers:
+        return upper
+    ids = (jnp.arange(layers, dtype=jnp.int32)[:, None] * n
+           + rows[None, :]).reshape(-1)
+    return upper.set_rows(ids, u_new.reshape(-1, upper.width))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
@@ -117,9 +173,8 @@ def _scatter_rows_jit(vectors, neighbors0, upper, levels,
     """Donated in-place row scatter: the resident buffers are updated
     without a whole-buffer copy (O(|rows|) work, not O(N))."""
     vectors = vectors.at[rows].set(v_new)
-    neighbors0 = neighbors0.at[rows].set(n0_new)
-    if upper.shape[0]:
-        upper = upper.at[:, rows].set(u_new)
+    neighbors0 = neighbors0.set_rows(rows, n0_new)
+    upper = _set_upper(upper, neighbors0.rows, rows, u_new)
     levels = levels.at[rows].set(l_new)
     return vectors, neighbors0, upper, levels
 
@@ -131,9 +186,8 @@ def _scatter_rows_scaled_jit(vectors, scales, neighbors0, upper, levels,
     its per-row scale travel together (DESIGN.md §9)."""
     vectors = vectors.at[rows].set(v_new)
     scales = scales.at[rows].set(s_new)
-    neighbors0 = neighbors0.at[rows].set(n0_new)
-    if upper.shape[0]:
-        upper = upper.at[:, rows].set(u_new)
+    neighbors0 = neighbors0.set_rows(rows, n0_new)
+    upper = _set_upper(upper, neighbors0.rows, rows, u_new)
     levels = levels.at[rows].set(l_new)
     return vectors, scales, neighbors0, upper, levels
 
@@ -149,14 +203,14 @@ def apply_row_updates(dg: DeviceGraph, g: HNSWGraph, rows,
     CONSUMES ``dg``: its buffers are donated to the updated graph, so the
     caller must drop its reference and use the returned DeviceGraph.
     Shapes must match (the host graph is the same capacity-padded view the
-    resident graph was built from). ``deleted`` refreshes the tombstone
+    resident graph was built from, ``DeviceGraph.fits``). ``deleted`` refreshes the tombstone
     mask; entry/max_level are always refreshed (scalar-cheap).
 
     ``enc``/``scales``: the codec-encoded capacity view when the resident
     graph stores encoded rows — dirty rows scatter the encoded payload
     (+ scale) instead of the f32 vectors (DESIGN.md §9).
     """
-    if dg.vectors.shape != g.vectors.shape or dg.upper.shape != g.upper.shape:
+    if not dg.fits(g):
         raise ValueError("capacity/layer shape changed; full rebuild required")
     rows = np.asarray(sorted(int(r) for r in rows), np.int32)
     if rows.size:
@@ -200,7 +254,7 @@ def apply_row_updates(dg: DeviceGraph, g: HNSWGraph, rows,
                                      neighbors0=neighbors0, upper=upper,
                                      levels=levels)
     new_deleted = dg.deleted if deleted is None \
-        else jnp.asarray(deleted[: dg.n], bool)
+        else _device_deleted(deleted, g.vectors.shape[0], dg.n)
     return dataclasses.replace(
         dg, entry=jnp.asarray(max(int(g.entry), 0), jnp.int32),
         deleted=new_deleted, max_level=int(g.max_level))
@@ -213,9 +267,8 @@ def _scatter_adj_jit(neighbors0, upper, rows, n0_new, u_new):
     vectors are unchanged — shipping full rows there would re-upload
     O(D) payload bytes per back-edge and erase the dirty-rows-only win
     (DESIGN.md §13). This path moves only the int32 adjacency."""
-    neighbors0 = neighbors0.at[rows].set(n0_new)
-    if upper.shape[0]:
-        upper = upper.at[:, rows].set(u_new)
+    neighbors0 = neighbors0.set_rows(rows, n0_new)
+    upper = _set_upper(upper, neighbors0.rows, rows, u_new)
     return neighbors0, upper
 
 
@@ -224,8 +277,7 @@ def apply_adjacency_updates(dg: DeviceGraph, g: HNSWGraph,
     """Scatter only neighbors0/upper for the dirty ``rows`` (vectors,
     levels, scales untouched) + refresh entry/max_level. Same donation
     contract as :func:`apply_row_updates`: CONSUMES ``dg``."""
-    if dg.neighbors0.shape != g.neighbors0.shape \
-            or dg.upper.shape != g.upper.shape:
+    if not dg.fits(g):
         raise ValueError("capacity/layer shape changed; full rebuild required")
     rows = np.asarray(sorted(int(r) for r in rows), np.int32)
     if rows.size:
@@ -266,8 +318,9 @@ def gather_distance(metric: str, vectors: jax.Array, q: jax.Array,
                     scales: jax.Array | None = None) -> jax.Array:
     """Fused gather(HBM)->distance: ids [B, K] (clamped), q [B, D] -> [B, K].
 
-    On TPU this routes to kernels/gather_distance.py; the jnp fallback keeps
-    identical semantics (invalid ids must be masked by the caller).
+    On TPU this always runs kernels/gather_distance.py; off-TPU the jnp
+    reference keeps identical semantics (invalid ids must be masked by
+    the caller).
     ``scales`` fuses the codec decode into the distance (DESIGN.md §9).
     """
     from repro.kernels import ops
@@ -290,15 +343,13 @@ def _prep_queries(g: DeviceGraph, queries) -> jax.Array:
 def _greedy_layer(g: DeviceGraph, q: jax.Array, ep: jax.Array,
                   ep_dist: jax.Array, layer: int) -> tuple[jax.Array, jax.Array]:
     """One layer's greedy descent. ep/ep_dist [B]. Static layer index."""
-    nbr_table = g.upper[layer - 1]          # [N, M]
-
     def cond(state):
         _, _, improved = state
         return jnp.any(improved)
 
     def body(state):
         ep, ep_dist, _ = state
-        nbrs = jnp.take(nbr_table, ep, axis=0)                 # [B, M]
+        nbrs = g.upper.take((layer - 1) * g.n + ep)            # [B, M]
         valid = nbrs >= 0
         ids = jnp.clip(nbrs, 0, g.n - 1)
         d = gather_distance(g.metric, g.vectors, q, ids, g.scales)
@@ -323,7 +374,7 @@ def _beam_search(g: DeviceGraph, q: jax.Array, ep: jax.Array,
                  ep_dist: jax.Array, ef: int, max_iters: int | None = None):
     """ef-beam best-first search on layer 0. Returns sorted (ids, dists)."""
     b = q.shape[0]
-    m2 = g.neighbors0.shape[1]
+    m2 = g.neighbors0.width
     # explicit None check: max_iters=0 means ZERO expansions (entry point
     # only), not "default to ef"
     max_iters = ef if max_iters is None else max_iters
@@ -346,7 +397,7 @@ def _beam_search(g: DeviceGraph, q: jax.Array, ep: jax.Array,
         cur = jnp.take_along_axis(beam_i, j[:, None], 1)[:, 0]
         beam_x = beam_x.at[jnp.arange(b), j].set(beam_x[jnp.arange(b), j] | has)
         # expand: gather 2M neighbors + distances
-        nbrs = jnp.take(g.neighbors0, jnp.clip(cur, 0, g.n - 1), axis=0)
+        nbrs = g.neighbors0.take(jnp.clip(cur, 0, g.n - 1))
         valid = (nbrs >= 0) & has[:, None]
         ids = jnp.clip(nbrs, 0, g.n - 1)
         d = gather_distance(g.metric, g.vectors, q, ids, g.scales)
@@ -388,6 +439,19 @@ def _beam_search_fused(g: DeviceGraph, q: jax.Array, ep: jax.Array,
         max_iters=max_iters)
 
 
+def descend(g: DeviceGraph, q: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Greedy descent from the entry point through every upper layer:
+    prepped queries [B, D] -> layer-0 entry points (ep [B], ep_dist [B])."""
+    ep = jnp.broadcast_to(g.entry, q.shape[:1])
+    x0 = jnp.take(g.vectors, ep, axis=0)
+    if g.scales is not None:                 # decode the entry row (§9)
+        x0 = x0.astype(jnp.float32) * jnp.take(g.scales, ep)[:, None]
+    ep_dist = batched_dist(g.metric, q, x0[:, None])[:, 0]
+    for layer in range(g.max_level, 0, -1):      # static unroll (few layers)
+        ep, ep_dist = _greedy_layer(g, q, ep, ep_dist, layer)
+    return ep, ep_dist
+
+
 def search_core(g: DeviceGraph, q: jax.Array, k: int, ef: int,
                 max_iters: int | None = None, beam_impl: str = "fused",
                 beam_expand: int | None = None):
@@ -403,13 +467,7 @@ def search_core(g: DeviceGraph, q: jax.Array, k: int, ef: int,
     if beam_impl not in ("fused", "jnp"):
         raise ValueError(f"unknown beam_impl {beam_impl!r}; "
                          "expected 'fused' or 'jnp'")
-    ep = jnp.broadcast_to(g.entry, q.shape[:1])
-    x0 = jnp.take(g.vectors, ep, axis=0)
-    if g.scales is not None:                 # decode the entry row (§9)
-        x0 = x0.astype(jnp.float32) * jnp.take(g.scales, ep)[:, None]
-    ep_dist = batched_dist(g.metric, q, x0[:, None])[:, 0]
-    for layer in range(g.max_level, 0, -1):      # static unroll (few layers)
-        ep, ep_dist = _greedy_layer(g, q, ep, ep_dist, layer)
+    ep, ep_dist = descend(g, q)
     if beam_impl == "fused":
         beam_i, beam_d = _beam_search_fused(g, q, ep, ep_dist, ef,
                                             max_iters, beam_expand)

@@ -363,7 +363,7 @@ class HNSW(VectorIndex):
         self._ensure_tombstones()
         g = b.graph_full_capacity(b.max_level_cap)   # fixed [12, cap, M] upper
         dg = self._device_graph
-        if dg is None or dg.vectors.shape != g.vectors.shape:
+        if dg is None or not dg.fits(g):
             # first upload, or capacity growth: full conversion
             enc, scl = self._enc_capacity(g.vectors.shape[0])
             self._device_graph = jhnsw.to_device_graph(

@@ -26,7 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.codec import (check_codec_arrays as _check_codec_arrays,
@@ -191,14 +191,14 @@ def _ivf_fanout_fn(mesh, k: int, nprobe: int, metric: str,
                       P(SHARD_AXIS, None), P(SHARD_AXIS, None),
                       P(None, None), P(None, None)),
             out_specs=(P(None, None), P(None, None)),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(fn)
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(SHARD_AXIS, None, None),
                              P(SHARD_AXIS, None, None), P(SHARD_AXIS, None),
                              P(None, None), P(None, None)),
                    out_specs=(P(None, None), P(None, None)),
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)
 
 
@@ -359,7 +359,7 @@ class IVFVectorIndex(VectorIndex):
         live = np.flatnonzero(self._rows.alive)
         if live.size == 0:
             raise ValueError("index is empty")
-        mesh, blocks, gids, scl, _slack = self._rows.pack()
+        mesh, blocks, gids, scl = self._rows.pack()
         cent, assign, nlist = self._coarse(live)
         s_lists: list[list[list[int]]] = [
             [[] for _ in range(nlist)] for _ in range(self.n_shards)]

@@ -42,7 +42,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.codec import VectorCodec, get_codec, rerank_exact
@@ -63,7 +63,7 @@ def resolve_wire_bf16(flag: bool | None) -> bool:
         return bool(flag)
     return os.environ.get("REPRO_WIRE_BF16", "0") == "1"
 # re-layout the slot tables when free (tombstoned/reusable) slots exceed
-# this fraction of block capacity: bounds the top-k slack (see pack())
+# this fraction of block capacity: bounds the scan work spent on free slots
 REPACK_FREE_FRACTION = 0.25
 
 
@@ -122,7 +122,7 @@ def trim_merge_width(d: jax.Array, ids: jax.Array, k: int, inf
 
 
 @functools.lru_cache(maxsize=64)
-def _fanout_topk_fn(mesh: Mesh, k: int, slack: int, metric: str,
+def _fanout_topk_fn(mesh: Mesh, k: int, metric: str,
                     has_scales: bool = False, wire_bf16: bool = False):
     """Compiled sharded exact top-k.
 
@@ -131,27 +131,24 @@ def _fanout_topk_fn(mesh: Mesh, k: int, slack: int, metric: str,
     Blocks may be codec-encoded (DESIGN.md §9); with ``has_scales`` a
     sharded [S, R] scale table rides along and the per-row decode fuses
     into the distance kernel. Slots with gid < 0 (free slots / block
-    padding) must not reach the merge, but the fused ``flat_topk``
-    kernel cannot mask mid-kernel — so each shard over-fetches
-    ``k + slack`` candidates (slack = the pack-time bound on dead slots
-    per shard), masks by gid, and re-selects k. Missing slots come back
-    as (INF, -1).
+    padding) are masked inside the fused ``flat_topk`` scan, so each
+    shard fetches exactly k; a shard with fewer live rows pads with
+    (INF, -1).
 
     The merge runs the ppermute tree reduction (static axis size from
     the mesh); ``wire_bf16`` halves its distance payload per round at
     the cost of bf16-resolution ordering (ids stay exact). Cache keys
-    are (mesh, k, quantized slack, metric, has_scales, wire_bf16) —
-    every component takes O(log R) or O(1) distinct values as the
-    corpus grows, so the lru_cache cannot churn across epochs.
+    are (mesh, k, metric, has_scales, wire_bf16): none grows with the
+    corpus, so the lru_cache cannot churn across epochs.
     """
     n_shards = mesh.shape[SHARD_AXIS]
 
     def local(blk, gid, q, scl=None):
         blk, gid = blk[0], gid[0]
         r = blk.shape[0]
-        kk = min(k + slack, r)
-        d, i = ops.flat_topk(blk, q, kk, metric=metric,
-                             scales=None if scl is None else scl[0])
+        d, i = ops.flat_topk(blk, q, min(k, r), metric=metric,
+                             scales=None if scl is None else scl[0],
+                             valid=gid >= 0)
         g = jnp.take(gid, i)
         d = jnp.where(g >= 0, d, jnp.float32(INF))
         d, g = trim_merge_width(d, g, k, jnp.float32(INF))
@@ -167,23 +164,14 @@ def _fanout_topk_fn(mesh: Mesh, k: int, slack: int, metric: str,
                                  P(SHARD_AXIS, None), P(SHARD_AXIS, None),
                                  P(None, None)),
                        out_specs=(P(None, None), P(None, None)),
-                       check_rep=False)
+                       check_vma=False)
         return jax.jit(fn)
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(SHARD_AXIS, None, None), P(SHARD_AXIS, None),
                              P(None, None)),
                    out_specs=(P(None, None), P(None, None)),
-                   check_rep=False)      # post-merge values ARE replicated
+                   check_vma=False)      # post-merge values ARE replicated
     return jax.jit(fn)
-
-
-def _quantize_slack(slack: int) -> int:
-    """Round the dead-slot bound up to a power of two so the compiled
-    fan-out is reused across nearby pack states (same trick as the
-    serving layer's batch buckets, DESIGN.md §6)."""
-    if slack <= 0:
-        return 0
-    return 1 << (slack - 1).bit_length()
 
 
 # incremented on every block upload — tests assert steady-state sharded
@@ -213,14 +201,10 @@ def place_blocks(blocks: np.ndarray, gids: np.ndarray, mesh: Mesh,
 class ExactBlocks:
     """Device-resident exact-phase row blocks, built once per mutation
     epoch and reused for every query until the index mutates (the same
-    invalidation contract the serve-layer LRU uses). ``slack`` is already
-    ``_quantize_slack``-rounded, so the compiled-fn cache key derived
-    from an ExactBlocks never takes more than O(log R) distinct values
-    as the corpus grows."""
+    invalidation contract the serve-layer LRU uses)."""
     mesh: Mesh
     blocks: jax.Array            # [S, R, D] sharded over "shard"
     gids: jax.Array              # [S, R] sharded over "shard"
-    slack: int                   # quantized over-fetch bound
     n_rows: int                  # total live rows across groups
 
 
@@ -242,16 +226,13 @@ def build_exact_blocks(groups, dim: int, *, normalize: bool = False
     r = max(v.shape[0] for v, _ in groups)
     blocks = np.zeros((s, r, dim), np.float32)
     gids = np.full((s, r), -1, np.int32)
-    slack = 0
     for j, (v, g) in enumerate(groups):
         if v.shape[0]:
             blocks[j, :v.shape[0]] = normalize_rows(v) if normalize else v
             gids[j, :v.shape[0]] = g
-        slack = max(slack, r - v.shape[0])
     mesh = shard_mesh(s)
     bl, gi = place_blocks(blocks, gids, mesh)
-    return ExactBlocks(mesh=mesh, blocks=bl, gids=gi,
-                       slack=_quantize_slack(slack), n_rows=total)
+    return ExactBlocks(mesh=mesh, blocks=bl, gids=gi, n_rows=total)
 
 
 def exact_topk_blocks(placed: ExactBlocks, queries, k: int, *, metric: str,
@@ -263,7 +244,7 @@ def exact_topk_blocks(placed: ExactBlocks, queries, k: int, *, metric: str,
     q = jnp.asarray(queries, jnp.float32)
     if metric == "cosine":
         q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
-    fn = _fanout_topk_fn(placed.mesh, k, placed.slack, metric,
+    fn = _fanout_topk_fn(placed.mesh, k, metric,
                          wire_bf16=resolve_wire_bf16(wire_bf16))
     d, g = fn(placed.blocks, placed.gids, q)
     return np.asarray(d), np.asarray(g)
@@ -341,7 +322,7 @@ class ShardedRows:
         self._slots: list[list[int]] = [[] for _ in range(n_shards)]
         self._free: list[list[int]] = [[] for _ in range(n_shards)]
         # device (lazy)
-        self._device = None          # S>1: (mesh, blocks, gids, scl, slack)
+        self._device = None          # S>1: (mesh, blocks, gids, scl)
         self._flat = None            # S==1: FlatIndex over live rows
         self._live_rows: np.ndarray | None = None
 
@@ -596,8 +577,8 @@ class ShardedRows:
 
         S == 1 -> a ``FlatIndex`` (bit-for-bit the pre-shard path for
                   fp32; encoded rows + scale column for lossy codecs).
-        S > 1  -> (mesh, blocks [S,R,D], gids [S,R], scales [S,R]|None,
-                  slack). Blocks hold the codec-encoded rows, so device
+        S > 1  -> (mesh, blocks [S,R,D], gids [S,R], scales [S,R]|None).
+                  Blocks hold the codec-encoded rows, so device
                   bytes shrink with the codec (DESIGN.md §9).
         """
         live = np.flatnonzero(self._alive)
@@ -633,10 +614,7 @@ class ShardedRows:
             gids = np.full((self.n_shards, r), -1, np.int32)
             scl = (np.zeros((self.n_shards, r), np.float32)
                    if self._scales is not None else None)
-            slack = 0
             for s in range(self.n_shards):
-                dead = r - (len(self._slots[s]) - len(self._free[s]))
-                slack = max(slack, dead)
                 table = np.asarray(self._slots[s], np.int64)
                 occ = np.flatnonzero(table >= 0)     # occupied slots only
                 if occ.size:
@@ -654,7 +632,7 @@ class ShardedRows:
                 sc = None
             else:
                 bl, gi, sc = place_blocks(blocks, gids, mesh, scl)
-            self._device = (mesh, bl, gi, sc, _quantize_slack(slack))
+            self._device = (mesh, bl, gi, sc)
         return self._device
 
     # -------------------------------------------------------------- search
@@ -673,12 +651,12 @@ class ShardedRows:
             d, i = flat.query(q, min(k, flat.n))
             d, i = np.asarray(d), np.asarray(i)
             return d, self._live_rows[i]
-        mesh, blocks, gids, scl, slack = self.pack()
+        mesh, blocks, gids, scl = self.pack()
         qj = jnp.asarray(q)
         if self.metric == "cosine" and self.normalize_on_pack:
             qj = qj / jnp.maximum(
                 jnp.linalg.norm(qj, axis=-1, keepdims=True), 1e-12)
-        fn = _fanout_topk_fn(mesh, k, slack, self.metric,
+        fn = _fanout_topk_fn(mesh, k, self.metric,
                              has_scales=scl is not None,
                              wire_bf16=resolve_wire_bf16(self.wire_bf16))
         d, g = (fn(blocks, gids, scl, qj) if scl is not None
@@ -704,5 +682,5 @@ class ShardedRows:
             if packed.scales is not None:
                 total += packed.scales.nbytes
             return total
-        _, bl, gi, sc, _ = packed
+        _, bl, gi, sc = packed
         return bl.nbytes + gi.nbytes + (sc.nbytes if sc is not None else 0)

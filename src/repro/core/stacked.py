@@ -30,13 +30,14 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import dispatch
 from repro.core import hnsw as jhnsw
 from repro.core.sharded import SHARD_AXIS, resolve_wire_bf16
 from repro.distributed.collectives import hierarchical_topk
+from repro.kernels.layout import LANES, PackedRows
 
 INF = np.float32(3e38)
 
@@ -56,8 +57,8 @@ class StackedGraphs:
     always exactly the index's shard count."""
     mesh: Mesh
     vectors: jax.Array      # [S, cap, D] storage dtype (DESIGN.md §9)
-    neighbors0: jax.Array   # [S, cap, 2M] int32, -1 pad
-    upper: jax.Array        # [S, L, cap, M] int32, -1 pad
+    neighbors0: jax.Array   # [S, cap/R, 128] int32: packed [cap, 2M], -1 pad
+    upper: jax.Array        # [S, L·cap/R, 128] int32: packed [L·cap, M]
     levels: jax.Array       # [S, cap] int32
     entry: jax.Array        # [S] int32
     deleted: jax.Array      # [S, cap] bool tombstones
@@ -65,6 +66,8 @@ class StackedGraphs:
     max_level: int          # max over shards: static descent unroll depth
     metric: str
     cap: int                # padded per-shard capacity: gid = s*cap + node
+    m2: int                 # layer-0 neighbor width (2M)
+    m: int                  # upper-layer neighbor width (M)
 
 
 def stack_device_graphs(graphs: list[jhnsw.DeviceGraph | None],
@@ -77,10 +80,10 @@ def stack_device_graphs(graphs: list[jhnsw.DeviceGraph | None],
     if not live:
         raise ValueError("index is empty")
     proto = live[0]
-    cap = max(g.n for g in live)
-    layers = proto.upper.shape[0]
-    m = proto.upper.shape[2] if proto.upper.ndim == 3 else 1
-    m2 = proto.neighbors0.shape[1]
+    cap = max(g.n for g in live)         # each a device_capacity: aligned
+    layers = proto.n_layers
+    m, per_u = proto.upper.width, proto.upper.per
+    m2, per = proto.neighbors0.width, proto.neighbors0.per
     dim = proto.vectors.shape[1]
     has_scales = proto.scales is not None
     vecs, n0s, ups, lvls, ents, dels, scls = [], [], [], [], [], [], []
@@ -89,8 +92,9 @@ def stack_device_graphs(graphs: list[jhnsw.DeviceGraph | None],
             # unreachable placeholder: no edges, entry 0, everything
             # tombstoned — the beam returns (INF, -1) for this shard
             vecs.append(jnp.zeros((cap, dim), proto.vectors.dtype))
-            n0s.append(jnp.full((cap, m2), -1, jnp.int32))
-            ups.append(jnp.full((layers, cap, m), -1, jnp.int32))
+            n0s.append(jnp.full((cap // per, LANES), -1, jnp.int32))
+            ups.append(jnp.full((layers * cap // per_u, LANES), -1,
+                                jnp.int32))
             lvls.append(jnp.zeros((cap,), jnp.int32))
             ents.append(jnp.zeros((), jnp.int32))
             dels.append(jnp.ones((cap,), bool))
@@ -99,10 +103,11 @@ def stack_device_graphs(graphs: list[jhnsw.DeviceGraph | None],
             continue
         pad = cap - g.n
         vecs.append(jnp.pad(g.vectors, ((0, pad), (0, 0))))
-        n0s.append(jnp.pad(g.neighbors0, ((0, pad), (0, 0)),
+        n0s.append(jnp.pad(g.neighbors0.table, ((0, pad // per), (0, 0)),
                            constant_values=-1))
-        ups.append(jnp.pad(g.upper, ((0, 0), (0, pad), (0, 0)),
-                           constant_values=-1))
+        up = g.upper.table.reshape(layers, g.n // per_u, LANES)
+        ups.append(jnp.pad(up, ((0, 0), (0, pad // per_u), (0, 0)),
+                           constant_values=-1).reshape(-1, LANES))
         lvls.append(jnp.pad(g.levels, (0, pad)))
         ents.append(g.entry)
         dels.append(jnp.pad(g.deleted, (0, pad), constant_values=True))
@@ -116,20 +121,22 @@ def stack_device_graphs(graphs: list[jhnsw.DeviceGraph | None],
         mesh=mesh,
         vectors=put(jnp.stack(vecs), None, None),
         neighbors0=put(jnp.stack(n0s), None, None),
-        upper=put(jnp.stack(ups), None, None, None),
+        upper=put(jnp.stack(ups), None, None),
         levels=put(jnp.stack(lvls), None),
         entry=put(jnp.stack(ents)),
         deleted=put(jnp.stack(dels), None),
         scales=put(jnp.stack(scls), None) if has_scales else None,
         max_level=max(g.max_level for g in live),
         metric=proto.metric,
-        cap=cap)
+        cap=cap,
+        m2=m2,
+        m=m)
 
 
 @functools.lru_cache(maxsize=32)
 def _stacked_search_fn(mesh: Mesh, k: int, ef: int, metric: str,
                        max_level: int, has_scales: bool, wire_bf16: bool,
-                       beam_impl: str = "fused"):
+                       beam_impl: str, m2: int, m: int):
     """Compiled stacked fan-out: every shard runs the full lock-step
     search (``hnsw.search_core`` — greedy descent + ef-beam + tombstone
     filter) over its own slice, then the per-shard top-k merges through
@@ -138,14 +145,15 @@ def _stacked_search_fn(mesh: Mesh, k: int, ef: int, metric: str,
     their descent terminates after one probe per layer.
 
     Cache keys are (mesh, k, ef, metric, max_level, has_scales,
-    wire_bf16) — all O(1)-valued per index configuration (max_level is
+    wire_bf16, beam_impl, m2, m) — all O(1)-valued per index configuration (max_level is
     bounded by the builder's layer cap), so the cache cannot churn."""
     n_shards = mesh.shape[SHARD_AXIS]
 
     def local(vectors, neighbors0, upper, levels, entry, deleted, q,
               scl=None):
         g = jhnsw.DeviceGraph(
-            vectors=vectors[0], neighbors0=neighbors0[0], upper=upper[0],
+            vectors=vectors[0], neighbors0=PackedRows(neighbors0[0], m2),
+            upper=PackedRows(upper[0], m),
             levels=levels[0], entry=entry[0], deleted=deleted[0],
             max_level=max_level, metric=metric,
             scales=None if scl is None else scl[0])
@@ -159,7 +167,7 @@ def _stacked_search_fn(mesh: Mesh, k: int, ef: int, metric: str,
                                  axis_sizes=(n_shards,))
 
     graph_specs = (P(SHARD_AXIS, None, None), P(SHARD_AXIS, None, None),
-                   P(SHARD_AXIS, None, None, None), P(SHARD_AXIS, None),
+                   P(SHARD_AXIS, None, None), P(SHARD_AXIS, None),
                    P(SHARD_AXIS), P(SHARD_AXIS, None))
     out_specs = (P(None, None), P(None, None))
     if has_scales:
@@ -170,11 +178,11 @@ def _stacked_search_fn(mesh: Mesh, k: int, ef: int, metric: str,
             mesh=mesh,
             in_specs=graph_specs + (P(SHARD_AXIS, None), P(None, None)),
             out_specs=out_specs,
-            check_rep=False)     # post-merge values ARE replicated
+            check_vma=False)     # post-merge values ARE replicated
         return jax.jit(fn)
     fn = shard_map(local, mesh=mesh,
                    in_specs=graph_specs + (P(None, None),),
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -196,7 +204,8 @@ def search_stacked(st: StackedGraphs, queries, k: int, ef: int,
                             1e-12)
     fn = _stacked_search_fn(st.mesh, k, max(ef, k), st.metric,
                             st.max_level, st.scales is not None,
-                            resolve_wire_bf16(wire_bf16), beam_impl)
+                            resolve_wire_bf16(wire_bf16), beam_impl, st.m2,
+                            st.m)
     DISPATCH_COUNT += 1
     dispatch.bump("stacked.search_stacked")
     dispatch.bump("stacked.beam_launches",

@@ -57,15 +57,14 @@ import urllib.parse
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.codec import VectorCodec, effective_rerank, get_codec
 from repro.core.flat import FlatVectorIndex, _pad_results
 from repro.core.hnsw_build import normalize_rows
-from repro.core.sharded import (INF, SHARD_AXIS, ShardedRows, _quantize_slack,
-                                place_blocks, shard_mesh, shard_of_key,
-                                trim_merge_width)
+from repro.core.sharded import (INF, SHARD_AXIS, ShardedRows, place_blocks,
+                                shard_mesh, shard_of_key, trim_merge_width)
 from repro.distributed.collectives import hierarchical_topk
 from repro.kernels import ops
 
@@ -109,17 +108,16 @@ def _slab_gather(blocks, gids, scl, tbl, slab_rows: int):
     return db, g, s
 
 
-def _slab_local_topk(blocks, gids, scl, tbl, q, *, k: int, slack: int,
-                     metric: str, slab_rows: int):
+def _slab_local_topk(blocks, gids, scl, tbl, q, *, k: int, metric: str,
+                     slab_rows: int):
     """One shard's tenant-scoped top-k: gather the tenant's slabs, run
     the SAME fused ``flat_topk`` kernel the single-index path uses over
-    the [L*R, D] gathered db, over-fetch ``k + slack`` (slack bounds the
-    invalid rows: free slots inside the tenant's slabs + whole padding
-    slabs — the kernel cannot mask mid-scan, DESIGN.md §8), mask by gid,
-    and trim to the k-wide merge format."""
+    the [L*R, D] gathered db with the invalid rows (free slots inside the
+    tenant's slabs + whole padding slabs) masked in the scan, and trim to
+    the k-wide merge format."""
     db, g, s = _slab_gather(blocks, gids, scl, tbl, slab_rows)
-    kk = min(k + slack, db.shape[0])
-    d, i = ops.flat_topk(db, q, kk, metric=metric, scales=s)
+    d, i = ops.flat_topk(db, q, min(k, db.shape[0]), metric=metric,
+                         scales=s, valid=g >= 0)
     gg = jnp.take(g, i)
     d = jnp.where(gg >= 0, d, jnp.float32(INF))
     d, gg = trim_merge_width(d, gg, k, jnp.float32(INF))
@@ -128,14 +126,14 @@ def _slab_local_topk(blocks, gids, scl, tbl, q, *, k: int, slack: int,
 
 
 @functools.lru_cache(maxsize=256)
-def _slab_topk_single(k: int, slack: int, metric: str, has_scales: bool,
+def _slab_topk_single(k: int, metric: str, has_scales: bool,
                       slab_rows: int):
     """S == 1 tenant search: one fused dispatch over the gathered slabs."""
     def run(blocks, gids, scl, tbl, q):
         if metric == "cosine":
             q = q / jnp.maximum(
                 jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
-        return _slab_local_topk(blocks, gids, scl, tbl, q, k=k, slack=slack,
+        return _slab_local_topk(blocks, gids, scl, tbl, q, k=k,
                                 metric=metric, slab_rows=slab_rows)
 
     if has_scales:
@@ -145,8 +143,8 @@ def _slab_topk_single(k: int, slack: int, metric: str, has_scales: bool,
 
 
 @functools.lru_cache(maxsize=256)
-def _slab_topk_sharded(mesh, k: int, slack: int, metric: str,
-                       has_scales: bool, slab_rows: int):
+def _slab_topk_sharded(mesh, k: int, metric: str, has_scales: bool,
+                       slab_rows: int):
     """S > 1 tenant search: per-shard slab gather + fused scan under
     shard_map, merged through the same ppermute tree as the single-index
     fan-out (ids exact, ties break on the smaller gid)."""
@@ -158,7 +156,7 @@ def _slab_topk_sharded(mesh, k: int, slack: int, metric: str,
         if metric == "cosine":
             q = q / jnp.maximum(
                 jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
-        d, gg = _slab_local_topk(blocks, gids, scl, tbl, q, k=k, slack=slack,
+        d, gg = _slab_local_topk(blocks, gids, scl, tbl, q, k=k,
                                  metric=metric, slab_rows=slab_rows)
         return hierarchical_topk(d, gg, k, (SHARD_AXIS,), tie_break_ids=True,
                                  axis_sizes=(n_shards,))
@@ -169,14 +167,14 @@ def _slab_topk_sharded(mesh, k: int, slack: int, metric: str,
                                  P(SHARD_AXIS, None), P(SHARD_AXIS, None),
                                  P(SHARD_AXIS, None), P(None, None)),
                        out_specs=(P(None, None), P(None, None)),
-                       check_rep=False)
+                       check_vma=False)
     else:
         fn = shard_map(lambda b, g, t, q: local(b, g, None, t, q), mesh=mesh,
                        in_specs=(P(SHARD_AXIS, None, None),
                                  P(SHARD_AXIS, None), P(SHARD_AXIS, None),
                                  P(None, None)),
                        out_specs=(P(None, None), P(None, None)),
-                       check_rep=False)
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -186,8 +184,7 @@ def _multi_local_topk(blocks, gids, scl, tbl, q, *, k: int, metric: str,
     slab table. tbl [B, L], q [B, D] -> (d [B, k], gids [B, k]).
 
     Per-query gather ([B, L, R, D]) + masked einsum + top_k: unlike the
-    single-tenant path the mask is applied BEFORE selection (this path is
-    plain jnp, not the fused kernel), so no slack over-fetch is needed.
+    single-tenant path this one is plain jnp, not the fused kernel.
     Rows are decoded in-graph (bf16 upcast / int8 * scale) — the same
     asymmetric-scan semantics as ``flat_topk``'s fused decode.
     """
@@ -251,14 +248,14 @@ def _slab_topk_multi(mesh, k: int, metric: str, has_scales: bool,
                                  P(SHARD_AXIS, None), P(SHARD_AXIS, None),
                                  P(SHARD_AXIS, None, None), P(None, None)),
                        out_specs=(P(None, None), P(None, None)),
-                       check_rep=False)
+                       check_vma=False)
     else:
         fn = shard_map(lambda b, g, t, q: local(b, g, None, t, q), mesh=mesh,
                        in_specs=(P(SHARD_AXIS, None, None),
                                  P(SHARD_AXIS, None),
                                  P(SHARD_AXIS, None, None), P(None, None)),
                        out_specs=(P(None, None), P(None, None)),
-                       check_rep=False)
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -513,12 +510,11 @@ class SlabRows(ShardedRows):
 
     # ------------------------------------------------------------- search
     def tenant_table(self, tid: str):
-        """-> (tbl [S, L] int32 slab ids (-1 pad), L, quantized slack,
-        live rows). L is the tenant's per-shard slab count rounded up to
-        a power of two, so the compiled search is shared across tenants
-        of similar size (the batch-bucket trick, DESIGN.md §6); slack
-        bounds the invalid rows per shard (free slots + padding slabs).
-        Cached per ``pack_epoch``."""
+        """-> (tbl [S, L] int32 slab ids (-1 pad), L, live rows). L is
+        the tenant's per-shard slab count rounded up to a power of two,
+        so the compiled search is shared across tenants of similar size
+        (the batch-bucket trick, DESIGN.md §6). Cached per
+        ``pack_epoch``."""
         ent = self._tables.get(tid)
         if ent is not None and ent[0] == self.pack_epoch:
             return ent[1:]
@@ -528,20 +524,16 @@ class SlabRows(ShardedRows):
         l_pad = 1 if mx <= 1 else 1 << (mx - 1).bit_length()
         tbl = np.full((s_n, l_pad), -1, np.int32)
         live = 0
-        slack = 0
         for s in range(s_n):
-            shard_live = 0
             for c, j in enumerate(per[s]):
                 tbl[s, c] = j
-                shard_live += r - len(self._slab_free[s][j])
-            live += shard_live
-            slack = max(slack, l_pad * r - shard_live)
-        out = (tbl, l_pad, _quantize_slack(slack), live)
+                live += r - len(self._slab_free[s][j])
+        out = (tbl, l_pad, live)
         self._tables[tid] = (self.pack_epoch,) + out
         return out
 
     def tenant_live(self, tid: str) -> int:
-        return self.tenant_table(tid)[3]
+        return self.tenant_table(tid)[2]
 
     def tenant_topk(self, tid: str, queries: np.ndarray, k: int
                     ) -> tuple[np.ndarray, np.ndarray]:
@@ -549,19 +541,19 @@ class SlabRows(ShardedRows):
         arena gids [B, k], (INF, -1)-padded). One compiled dispatch; the
         db it scans is the tenant's slabs gathered in-graph, so cost
         scales with the tenant, not the arena."""
-        tbl, _, slack, live = self.tenant_table(tid)
+        tbl, _, live = self.tenant_table(tid)
         if live == 0:
             raise ValueError("index is empty")
         q = jnp.asarray(np.asarray(queries, np.float32))
         mesh, blocks, gids, scl = self.pack_arena()
         if mesh is None:
-            fn = _slab_topk_single(k, slack, self.metric, scl is not None,
+            fn = _slab_topk_single(k, self.metric, scl is not None,
                                    self.slab_rows)
             args = (blocks, gids) + (() if scl is None else (scl,)) \
                 + (jnp.asarray(tbl[0]), q)
         else:
-            fn = _slab_topk_sharded(mesh, k, slack, self.metric,
-                                    scl is not None, self.slab_rows)
+            fn = _slab_topk_sharded(mesh, k, self.metric, scl is not None,
+                                    self.slab_rows)
             args = (blocks, gids) + (() if scl is None else (scl,)) \
                 + (jnp.asarray(tbl), q)
         d, g = fn(*args)
@@ -974,7 +966,7 @@ class IndexPool:
         # dedicated empty index would
         by_l: dict[int, list[int]] = {}
         for i, tid in enumerate(tenants):
-            _, l_pad, _, live = self._arena.tenant_table(tid)
+            _, l_pad, live = self._arena.tenant_table(tid)
             if live == 0:
                 raise ValueError("index is empty")
             by_l.setdefault(l_pad, []).append(i)

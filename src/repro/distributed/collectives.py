@@ -186,9 +186,7 @@ def compressed_psum(x: jax.Array, axis_name: str) -> jax.Array:
     payloads — 4x wire-byte reduction vs f32 ring all-reduce. Per-shard
     scale factors travel as f32 scalars (negligible).
     """
-    # axis size via the psum-of-ones idiom: works on every JAX that supports
-    # shard_map (jax.lax.axis_size is not present in the installed version)
-    s = jax.lax.psum(1, axis_name)
+    s = jax.lax.axis_size(axis_name)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % s
     flat = jnp.pad(flat, (0, pad))

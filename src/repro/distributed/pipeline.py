@@ -18,7 +18,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(mesh: Mesh, axis: str, stage_fn: Callable,
@@ -74,7 +74,7 @@ def pipeline_apply(mesh: Mesh, axis: str, stage_fn: Callable,
     fn = shard_map(local, mesh=mesh,
                    in_specs=(p_specs, P()),
                    out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn(stage_params, x_micro)
 
 

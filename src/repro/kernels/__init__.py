@@ -6,20 +6,27 @@ from __future__ import annotations
 import os
 
 
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU."""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
 def resolve_interpret(interpret: bool | None) -> bool:
     """Platform-aware default for the Pallas ``interpret`` flag.
 
-    ``None`` (the default in the retrieval kernels) resolves to
-    "interpret only off-TPU": a TPU process compiles the real kernels
-    without every caller having to pass ``interpret=False``, while CPU
-    runs keep executing the same kernels under the interpreter. Override
-    per-call with an explicit bool, or process-wide with
-    ``REPRO_PALLAS_INTERPRET=1|0``.
+    On a TPU backend ``None`` always resolves to the compiled kernel: no
+    environment variable can send a TPU process to the interpreter.
+    Off-TPU, ``None`` resolves to the interpreter, and
+    ``REPRO_PALLAS_INTERPRET=1|0`` overrides that for the CPU tests. An
+    explicit bool always wins (the compile tests pass ``False`` to build
+    the TPU kernel from a CPU process).
     """
     if interpret is not None:
         return bool(interpret)
+    if on_tpu():
+        return False
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if env is not None:
         return env.lower() not in ("0", "false", "")
-    import jax
-    return jax.default_backend() != "tpu"
+    return True

@@ -22,10 +22,11 @@ Grid / block layout
   holds the running weighted sums; the combine normalisation happens once
   at the end. ``wave`` is shrunk to divide block_b*L.
 
-Fallback
-  ``interpret=True`` runs the kernel under the Pallas interpreter (CPU
-  kernel tests). ``ops.embedding_bag`` picks Pallas only on TPU (or
-  REPRO_PALLAS=interpret); otherwise the jnp oracle
+Platforms
+  ``interpret=None`` resolves platform-aware (kernels.resolve_interpret):
+  compiled on TPU, the interpreter elsewhere. ``ops.embedding_bag``
+  picks Pallas on every TPU call, and off-TPU only under
+  REPRO_PALLAS=interpret; otherwise the jnp oracle
   ``ref.embedding_bag_ref`` does the gather-then-reduce in HBM — same
   numbers, more traffic. The recsys models route through ``ops``.
 """
@@ -37,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _kernel(combine: str, wave: int, ids_ref, w_ref, table_ref, out_ref,
@@ -96,13 +99,20 @@ def _kernel(combine: str, wave: int, ids_ref, w_ref, table_ref, out_ref,
         out_ref[...] = acc[...]
 
 
-@functools.partial(jax.jit, static_argnames=("combine", "block_b", "wave",
-                                             "interpret"))
 def embedding_bag_pallas(table: jax.Array, ids: jax.Array,
                          weights: jax.Array | None = None,
                          *, combine: str = "sum", block_b: int = 8,
-                         wave: int = 8, interpret: bool = True) -> jax.Array:
-    """table [R,E] (HBM), ids [B,L], weights [B,L] -> bags [B,E] f32."""
+                         wave: int = 8,
+                         interpret: bool | None = None) -> jax.Array:
+    """table [R,E] (HBM), ids [B,L], weights [B,L] -> bags [B,E] f32.
+    ``interpret=None`` resolves platform-aware."""
+    return _call(table, ids, weights, combine, block_b, wave,
+                 resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("combine", "block_b", "wave",
+                                             "interpret"))
+def _call(table, ids, weights, combine, block_b, wave, interpret):
     b, l = ids.shape
     e = table.shape[1]
     if weights is None:

@@ -25,12 +25,13 @@ Grid / block layout
   the j axis (sequential grid dim on TPU); tile 0 initialises them, the
   last tile writes acc / l. block_s is shrunk to divide S.
 
-Fallback
-  ``interpret=True`` runs the kernel under the Pallas interpreter.
-  ``ops.flash_decode`` dispatches to Pallas only on TPU (or
-  REPRO_PALLAS=interpret); elsewhere the jnp oracle
-  ``ref.flash_decode_ref`` computes the same masked softmax-attention in
-  one shot. ``models/transformer.py``'s decode step consumes either.
+Platforms
+  ``interpret=None`` resolves platform-aware (kernels.resolve_interpret):
+  the compiled kernel on TPU, always; the Pallas interpreter elsewhere.
+  ``ops.flash_decode`` runs this kernel on every TPU call. Off-TPU it
+  runs the jnp oracle ``ref.flash_decode_ref`` (the same masked
+  softmax-attention in one shot) unless REPRO_PALLAS=interpret.
+  ``models/transformer.py``'s decode step consumes either.
 """
 from __future__ import annotations
 
@@ -40,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG = -1e30   # plain float: pallas kernels must not capture traced constants
 
@@ -90,11 +93,16 @@ def _kernel(st: int, kvh: int, g: int, cur_ref, q_ref, k_ref, v_ref, out_ref,
                       / jnp.maximum(l_sc[...], 1e-30)).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                         cur_len: jax.Array, *, block_s: int = 512,
-                        interpret: bool = True) -> jax.Array:
-    """q [B,H,Dh]; k,v [B,S,KVH,Dh]; cur_len scalar or [B] i32 -> [B,H,Dh] f32."""
+                        interpret: bool | None = None) -> jax.Array:
+    """q [B,H,Dh]; k,v [B,S,KVH,Dh]; cur_len scalar or [B] i32 -> [B,H,Dh] f32.
+    ``interpret=None`` resolves platform-aware."""
+    return _call(q, k, v, cur_len, block_s, resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
+def _call(q, k, v, cur_len, block_s, interpret):
     b, h, dh = q.shape
     s, kvh = k.shape[1], k.shape[2]
     g = h // kvh

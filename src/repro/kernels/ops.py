@@ -1,9 +1,12 @@
 """Public jit'd wrappers for the kernel layer, with backend dispatch.
 
-Dispatch policy (env ``REPRO_PALLAS``):
-  "auto" (default) — Pallas (compiled) on TPU; pure-jnp reference elsewhere
-  "interpret"      — Pallas in interpret mode everywhere (kernel tests)
-  "off"            — always the jnp reference
+On a TPU backend every op here runs its compiled Pallas kernel, always:
+no environment variable routes a TPU process to the jnp reference or
+to the interpreter.
+
+Off-TPU (the CPU tests), ``REPRO_PALLAS=interpret`` runs the Pallas
+kernel under the interpreter; unset, or any other value, runs the
+pure-jnp reference.
 
 The jnp reference paths are the same oracles the kernel tests assert
 against, so behaviour is identical either way.
@@ -16,22 +19,16 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels import ref as _ref
-
-
-def _mode() -> str:
-    return os.environ.get("REPRO_PALLAS", "auto")
 
 
 def _use_pallas() -> tuple[bool, bool]:
     """-> (use_pallas, interpret)."""
-    m = _mode()
-    if m == "off":
-        return False, False
-    if m == "interpret":
-        return True, True
-    on_tpu = jax.default_backend() == "tpu"
-    return on_tpu, False
+    if on_tpu():
+        return True, False
+    interp = os.environ.get("REPRO_PALLAS") == "interpret"
+    return interp, interp
 
 
 # ---------------------------------------------------------------------------
@@ -103,20 +100,24 @@ def select_neighbors(vectors: jax.Array, q: jax.Array, cand_ids: jax.Array,
 
 def flat_topk(db: jax.Array, q: jax.Array, k: int,
               *, metric: str = "cosine",
-              scales: jax.Array | None = None
+              scales: jax.Array | None = None,
+              valid: jax.Array | None = None
               ) -> tuple[jax.Array, jax.Array]:
     """Exact k-NN: db [N,D], q [B,D] -> (dists [B,k], ids [B,k]).
 
     ``db`` may be codec-encoded (f32 / bf16 / int8, DESIGN.md §9);
-    ``scales`` [N] fuses the per-row decode into the distance."""
+    ``scales`` [N] fuses the per-row decode into the distance. Rows
+    where ``valid`` [N] is False never outrank a valid row."""
     use, interp = _use_pallas()
     if use:
         from repro.kernels.distance_topk import distance_topk_pallas
         pd, pi = distance_topk_pallas(db, q, k, metric=metric,
-                                      scales=scales, interpret=interp)
+                                      scales=scales, valid=valid,
+                                      interpret=interp)
         neg, j = jax.lax.top_k(-pd, k)                 # tiny [B, T*k] merge
         return -neg, jnp.take_along_axis(pi, j, axis=1)
-    return _ref.distance_topk_ref(db, q, k, metric=metric, scales=scales)
+    return _ref.distance_topk_ref(db, q, k, metric=metric, scales=scales,
+                                  valid=valid)
 
 
 def embedding_bag(table: jax.Array, ids: jax.Array,

@@ -7,6 +7,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.layout import next_pow2, row_width, take_rows
+
 
 def gather_distance_ref(vectors: jax.Array, q: jax.Array, ids: jax.Array,
                         *, metric: str = "cosine",
@@ -27,12 +29,14 @@ def gather_distance_ref(vectors: jax.Array, q: jax.Array, ids: jax.Array,
 
 def distance_topk_ref(db: jax.Array, q: jax.Array, k: int,
                       *, metric: str = "cosine",
-                      scales: jax.Array | None = None
+                      scales: jax.Array | None = None,
+                      valid: jax.Array | None = None
                       ) -> tuple[jax.Array, jax.Array]:
     """db [N,D], q [B,D] -> (dists [B,k] ascending, ids [B,k]).
 
     ``scales`` [N] decodes codec-encoded db rows in fp32 before the
-    distance (asymmetric distance, DESIGN.md §9)."""
+    distance (asymmetric distance, DESIGN.md §9). Rows where ``valid``
+    [N] is False score 3e38, the kernel's padding distance."""
     x = db.astype(jnp.float32)
     if scales is not None:
         x = x * scales.astype(jnp.float32)[:, None]
@@ -42,6 +46,8 @@ def distance_topk_ref(db: jax.Array, q: jax.Array, k: int,
         d = (jnp.sum(q.astype(jnp.float32) ** 2, -1)[:, None]
              - 2.0 * jnp.einsum("bd,nd->bn", q.astype(jnp.float32), x)
              + jnp.sum(x ** 2, -1)[None, :])
+    if valid is not None:
+        d = jnp.where(valid[None, :], d, jnp.float32(3.0e38))
     neg, ids = jax.lax.top_k(-d, k)
     return -neg, ids
 
@@ -74,75 +80,88 @@ def embedding_bag_ref(table: jax.Array, ids: jax.Array,
 BEAM_INF = 3.0e38
 
 
-def next_pow2(n: int) -> int:
-    return 1 if n <= 1 else 1 << (int(n) - 1).bit_length()
+
+def _lanes(x):
+    """Lane index of every element of ``x`` along its last axis (a 2-D
+    iota: Mosaic has no 1-D iota)."""
+    return jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
 
 
-def _compare_exchange(d, i, x, stride: int, asc_mask):
+def _roll(x, shift: int):
+    """``jnp.roll`` along the last axis (the default ``roll`` of the
+    network helpers below; a kernel passes a lane rotate instead)."""
+    return jnp.roll(x, shift, axis=-1)
+
+
+def _compare_exchange(d, i, x, stride: int, asc_mask, roll=_roll):
     """One bitonic compare-exchange stage on (dist, id, payload) triples
     along the last axis, ordered by the two-key (d, id) lexicographic
-    compare. ``asc_mask`` [W] is each position's block direction. The
-    partner of position p is p ^ stride — p+stride in lower halves,
-    p-stride in upper halves — so a pair of rolls never wraps a pair
-    across the array edge."""
-    lower = (jnp.arange(d.shape[-1]) & stride) == 0
-    pd = jnp.where(lower, jnp.roll(d, -stride, -1), jnp.roll(d, stride, -1))
-    pi = jnp.where(lower, jnp.roll(i, -stride, -1), jnp.roll(i, stride, -1))
-    px = jnp.where(lower, jnp.roll(x, -stride, -1), jnp.roll(x, stride, -1))
+    compare. ``asc_mask`` (same shape) is each position's block
+    direction. The partner of position p is p ^ stride — p+stride in
+    lower halves, p-stride in upper halves — so a pair of rolls never
+    wraps a pair across the array edge."""
+    lower = (_lanes(d) & stride) == 0
+    pd = jnp.where(lower, roll(d, -stride), roll(d, stride))
+    pi = jnp.where(lower, roll(i, -stride), roll(i, stride))
+    px = jnp.where(lower, roll(x, -stride), roll(x, stride))
     le = (d < pd) | ((d == pd) & (i <= pi))
-    keep = jnp.where(lower == asc_mask, le, ~le)
+    # where(lower == asc_mask, le, ~le) as xors: Mosaic cannot select
+    # between two masks
+    keep = le ^ lower ^ asc_mask
     return (jnp.where(keep, d, pd), jnp.where(keep, i, pi),
             jnp.where(keep, x, px))
 
 
-def bitonic_sort(d, i, x, *, ascending: bool = True):
+def bitonic_sort(d, i, x, *, ascending: bool = True, roll=_roll):
     """Full bitonic sort along the last axis (width must be a power of
     two) by the two-key (d, id) order. ~log²W compare-exchange stages of
     pure vector ops — no lax.sort, so the same network runs inside the
-    Pallas kernel body."""
+    Pallas kernel body (with ``roll`` a lane rotate). The payload ``x``
+    is int32 inside a kernel: a lane rotate of a bool mask does not
+    lower."""
     w = d.shape[-1]
-    idx = jnp.arange(w)
+    idx = _lanes(d)
     size = 2
     while size <= w:
         asc_mask = ((idx & size) == 0) == bool(ascending)
         stride = size // 2
         while stride:
-            d, i, x = _compare_exchange(d, i, x, stride, asc_mask)
+            d, i, x = _compare_exchange(d, i, x, stride, asc_mask, roll)
             stride //= 2
         size *= 2
     return d, i, x
 
 
-def bitonic_merge(d, i, x):
+def bitonic_merge(d, i, x, roll=_roll):
     """Bitonic merge: a bitonic input along the last axis (power-of-two
     width) sorts ascending in log W compare-exchange stages — the cheap
     half of a full sort, and the reason the beam stays sorted between
     hops instead of being re-sorted."""
-    asc = jnp.ones(d.shape[-1], bool)
+    asc = _lanes(d) >= 0
     stride = d.shape[-1] // 2
     while stride:
-        d, i, x = _compare_exchange(d, i, x, stride, asc)
+        d, i, x = _compare_exchange(d, i, x, stride, asc, roll)
         stride //= 2
     return d, i, x
 
 
-def beam_select_frontier(bd, bi, bx, t_live, t: int):
+def beam_frontier(bd, bi, bx, t_live, t: int):
     """Mark the first ``t_live`` (<= t) unexpanded entries of the
     (ascending-sorted) beam as expanded and extract their node ids.
-    Returns (new_bx, nodes [B, t] with -1 for unfilled slots). Rank among
-    unexpanded entries comes from a strict-lower-triangular matmul —
-    MXU-friendly and Mosaic-safe, where a lane cumsum is not."""
-    efp = bd.shape[-1]
+    Returns (new_bx, [t columns [B, 1]], -1 for unfilled slots). Rank
+    among unexpanded entries comes from a strict-lower-triangular matmul
+    — MXU-friendly and Mosaic-safe, where a lane cumsum is not."""
+    w = bd.shape[-1]
     unexp = (~bx) & (bi >= 0)
-    tri = (jnp.arange(efp)[:, None] < jnp.arange(efp)[None, :]
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+           < jax.lax.broadcasted_iota(jnp.int32, (w, w), 1)
            ).astype(jnp.float32)
     rank = jnp.dot(unexp.astype(jnp.float32), tri,
                    preferred_element_type=jnp.float32).astype(jnp.int32)
     sel = unexp & (rank < t_live)
-    nodes = jnp.stack(
-        [jnp.max(jnp.where(sel & (rank == j), bi, -1), axis=-1)
-         for j in range(t)], axis=-1)
-    return bx | sel, nodes
+    cols = [jnp.max(jnp.where(sel & (rank == j), bi, -1), axis=-1,
+                    keepdims=True) for j in range(t)]
+    return bx | sel, cols
 
 
 def beam_dedup_valid(cand, valid, bi):
@@ -159,13 +178,37 @@ def beam_dedup_valid(cand, valid, bi):
     return valid & ~in_beam & ~dup
 
 
+def merge_width(efp: int, w: int) -> int:
+    """Lane width of the one-network merge of an ``efp`` beam with ``w``
+    candidates (a power of two >= efp + w)."""
+    return next_pow2(efp + next_pow2(w))
+
+
+def beam_merge_wide(bd, bi, bx, cd, ci, ef: int, efp: int, roll=_roll):
+    """The bitonic merge on arrays that are all one power-of-two width L
+    >= efp + (live candidates): the beam ascending in lanes [0, efp) and
+    pads after, the candidates anywhere with (INF, -1) pads. Sorting the
+    candidates DESCENDING puts their pads first, so taking lanes
+    [0, efp) from the beam and the rest from the candidates is bitonic
+    by construction; one merge sorts it. Returns L-wide (dist, id,
+    payload) with every lane past ``ef`` reset to (INF, -1, expanded)."""
+    cd, ci, cx = bitonic_sort(cd, ci, jnp.zeros_like(bx), ascending=False,
+                              roll=roll)
+    head = _lanes(bd) < efp
+    md, mi, mx = bitonic_merge(jnp.where(head, bd, cd),
+                               jnp.where(head, bi, ci),
+                               jnp.where(head, bx, cx), roll)
+    live = _lanes(md) < ef
+    return (jnp.where(live, md, BEAM_INF), jnp.where(live, mi, -1),
+            jnp.where(live, mx, jnp.ones_like(mx)))
+
+
 def beam_merge(bd, bi, bx, cd, ci, ef: int, use_bitonic: bool = True):
-    """One-hop beam merge: bitonic-sort the candidates DESCENDING, glue
-    them after the already-ascending beam (+ an INF plateau up to the
-    next power of two) — the concatenation is bitonic by construction —
-    and run a single bitonic merge. Entries past ``ef`` reset to
-    (INF, -1, expanded) so the logical beam width stays exactly ef
-    (recall parity with the ef-wide reference beam).
+    """One-hop beam merge of the ascending beam [B, efp] with candidates
+    [B, w]: :func:`beam_merge_wide` after padding both to the merge
+    width. Entries past ``ef`` reset to (INF, -1, expanded) so the
+    logical beam width stays exactly ef (recall parity with the ef-wide
+    reference beam).
 
     ``use_bitonic=False`` swaps the network for one ``lax.sort`` over
     the plain concatenation — output-identical (live (d, id) keys are
@@ -184,24 +227,16 @@ def beam_merge(bd, bi, bx, cd, ci, ef: int, use_bitonic: bool = True):
         return (jnp.where(live, md[:, :efp], BEAM_INF),
                 jnp.where(live, mi[:, :efp], -1),
                 jnp.where(live, mx[:, :efp], True))
-    wp = next_pow2(w)
-    if wp > w:
-        cd = jnp.concatenate(
-            [cd, jnp.full((b, wp - w), BEAM_INF)], axis=-1)
-        ci = jnp.concatenate(
-            [ci, jnp.full((b, wp - w), -1, jnp.int32)], axis=-1)
-    cx = jnp.zeros((b, wp), bool)
-    cd, ci, cx = bitonic_sort(cd, ci, cx, ascending=False)
-    pad = next_pow2(efp + wp) - efp - wp
-    md = jnp.concatenate([bd, jnp.full((b, pad), BEAM_INF), cd], axis=-1)
-    mi = jnp.concatenate(
-        [bi, jnp.full((b, pad), -1, jnp.int32), ci], axis=-1)
-    mx = jnp.concatenate([bx, jnp.ones((b, pad), bool), cx], axis=-1)
-    md, mi, mx = bitonic_merge(md, mi, mx)
-    live = jnp.arange(efp) < ef
-    return (jnp.where(live, md[:, :efp], BEAM_INF),
-            jnp.where(live, mi[:, :efp], -1),
-            jnp.where(live, mx[:, :efp], True))
+    width = merge_width(efp, w)
+
+    def pad(a, fill):
+        return jnp.concatenate(
+            [a, jnp.full((b, width - a.shape[-1]), fill, a.dtype)], axis=-1)
+
+    md, mi, mx = beam_merge_wide(pad(bd, BEAM_INF), pad(bi, -1),
+                                 pad(bx, True), pad(cd, BEAM_INF),
+                                 pad(ci, -1), ef, efp)
+    return md[:, :efp], mi[:, :efp], mx[:, :efp]
 
 
 def beam_search_ref(vectors: jax.Array, neighbors0: jax.Array,
@@ -215,7 +250,7 @@ def beam_search_ref(vectors: jax.Array, neighbors0: jax.Array,
     per-hop DMA gather replaced by ``gather_distance_ref``.
 
     vectors [N, D] (any codec dtype; ``scales`` [N] decodes), neighbors0
-    [N, 2M] i32 (-1 pad), q [B, D] f32, ep/ep_dist [B] layer-0 entry
+    [N, 2M] i32 (-1 pad), dense or as ``layout.PackedRows``, q [B, D] f32, ep/ep_dist [B] layer-0 entry
     points. Returns (ids [B, ef], dists [B, ef]) ascending by (d, id);
     empty slots are (-1, INF).
 
@@ -225,7 +260,7 @@ def beam_search_ref(vectors: jax.Array, neighbors0: jax.Array,
     expand_t=1 the visit order is exactly the sequential-semantics
     ``core.hnsw._beam_search`` order."""
     b = q.shape[0]
-    n, m2 = neighbors0.shape
+    n, m2 = vectors.shape[0], row_width(neighbors0)
     t = max(1, min(int(expand_t), int(ef)))
     # default budget: ef, plus one slack hop at t>1 (kept in lockstep
     # with kernels/beam_search.py — group frontier selection needs the
@@ -246,8 +281,9 @@ def beam_search_ref(vectors: jax.Array, neighbors0: jax.Array,
     def body(state):
         bd, bi, bx, hop = state
         t_live = jnp.minimum(t, budget - hop * t)
-        bx, nodes = beam_select_frontier(bd, bi, bx, t_live, t)
-        nbrs = jnp.take(neighbors0, jnp.clip(nodes, 0, n - 1), axis=0)
+        bx, cols = beam_frontier(bd, bi, bx, t_live, t)
+        nodes = jnp.concatenate(cols, axis=-1)                # [B, t]
+        nbrs = take_rows(neighbors0, jnp.clip(nodes, 0, n - 1))
         valid = ((nodes >= 0)[:, :, None] & (nbrs >= 0)).reshape(b, t * m2)
         cand = jnp.clip(nbrs, 0, n - 1).reshape(b, t * m2)
         d = gather_distance_ref(vectors, q, cand, metric=metric,

@@ -25,7 +25,7 @@ from repro.data.corpus import BUILTIN_CORPUS
 from repro.models import transformer as tf
 from repro.serve.engine import ServeEngine
 from repro.serve.rag import RAGPipeline
-from repro.utils import logger
+from repro.utils import logger, use_compile_cache
 
 
 def _power_of_two(v: str) -> int:
@@ -66,7 +66,7 @@ def _log_engine_stats(engine):
         f"{s['re_retrievals']} epoch-guard re-retrievals")
 
 
-def main():
+def main(argv: list[str] | None = None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--slots", type=int, default=4)
@@ -126,7 +126,8 @@ def main():
                          "of the admission schedule")
     ap.add_argument("--temperature", type=float, default=1.0)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     params = tf.init_lm(jax.random.PRNGKey(args.seed), cfg)

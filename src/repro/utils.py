@@ -3,7 +3,9 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import os
 import time
+from pathlib import Path
 from typing import Any, Callable, Iterator
 
 import jax
@@ -18,6 +20,26 @@ if not logger.handlers:
     logger.setLevel(logging.INFO)
 
 PyTree = Any
+
+# the checkout's root: src/repro/utils.py -> <checkout>
+CHECKOUT = Path(__file__).resolve().parents[2]
+
+
+def use_compile_cache() -> str:
+    """Put JAX's persistent compilation cache in a fixed place; returns
+    the directory in use. Call it from a program's ``main()``, never at
+    import.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX takes the directory
+    from it and nothing is set here. Otherwise the cache goes to
+    ``<checkout>/.jax_cache``: the path is part of the cache key, so a
+    temporary or per-run directory would never hit."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------

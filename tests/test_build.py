@@ -235,7 +235,7 @@ def test_adjacency_updates_and_h2d_accounting(rng):
     dg = jhnsw.apply_adjacency_updates(dg, g, [7])
     adj_bytes = dispatch.get("hnsw.h2d_bytes")
     assert adj_bytes == 1 * 4 * (8 + lmax * 4)     # one row, no [D] payload
-    row = np.asarray(dg.neighbors0[7])
+    row = np.asarray(dg.neighbors0.take(7))
     assert row[0] == 3 and (row[1:] == -1).all()
     assert np.array_equal(np.asarray(dg.vectors), before)
     # the bulk path's whole-build traffic: one capacity upload + O(M)
@@ -254,6 +254,45 @@ def test_adjacency_updates_and_h2d_accounting(rng):
 
 
 # ------------------------------------------------------- sharded adoption
+def test_device_graph_owns_aligned_packed_layout(monkeypatch, rng):
+    """A host graph of N % 8 != 0 rows goes up at ``device_capacity`` rows
+    with its adjacency packed: the extra rows are zero, edgeless and
+    tombstoned, dirty rows scatter into the packed tables, and the
+    kernels (under the interpreter) search the padded graph exactly as
+    their jnp oracles do."""
+    from repro.kernels.layout import device_capacity
+    data = rng.normal(size=(1001, 16)).astype(np.float32)
+    g = hb.build_sequential(data, M=4, ef_construction=20, seed=0)
+    dg = jhnsw.to_device_graph(g)
+    cap = device_capacity(1001)
+    assert dg.n == cap == 1024 and dg.fits(g)
+    assert dg.neighbors0.table.shape == (cap // dg.neighbors0.per, 128)
+    assert dg.n_layers == g.upper.shape[0]
+    up = np.asarray(dg.upper.take(np.arange(dg.upper.rows)))
+    up = up.reshape(dg.n_layers, cap, -1)
+    assert np.array_equal(up[:, :1001], g.upper)
+    assert (up[:, 1001:] == -1).all()
+    nb = np.asarray(dg.neighbors0.take(np.arange(cap)))
+    assert np.array_equal(nb[:1001], g.neighbors0)
+    assert (nb[1001:] == -1).all()
+    assert np.asarray(dg.deleted)[1001:].all()
+    assert not np.asarray(dg.deleted)[:1001].any()
+    assert (np.asarray(dg.vectors)[1001:] == 0).all()
+    g.neighbors0[1000] = -1
+    g.neighbors0[1000, :2] = (3, 5)
+    dg = jhnsw.apply_adjacency_updates(dg, g, [1000])
+    assert np.array_equal(np.asarray(dg.neighbors0.take(1000)),
+                          g.neighbors0[1000])
+    q = data[:8] + 0.01
+    monkeypatch.delenv("REPRO_PALLAS", raising=False)
+    ids_r, d_r = jhnsw.search_graph(dg, q, k=10, ef=32)
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    ids_k, d_k = jhnsw.search_graph(dg, q, k=10, ef=32)
+    assert (np.asarray(ids_k) < 1001).all()
+    assert np.array_equal(np.asarray(ids_k), np.asarray(ids_r))
+    np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r), atol=1e-5)
+
+
 def test_reshard_adopts_bulk_built_graph():
     """A 1-shard bulk-built fp32 snapshot restored at n_shards=4 takes
     the bulk-adoption fast path: canonical key order survives, exact

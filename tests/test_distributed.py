@@ -99,7 +99,7 @@ def test_sharded_topk_bf16_wire_recall():
 # or all-gather oracle (axis_sizes=None), optionally with the bf16 wire
 _MERGE = """
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.sharded import SHARD_AXIS, shard_mesh
 from repro.distributed.collectives import hierarchical_topk
@@ -112,7 +112,7 @@ def merge(s, d, i, k, tree, wire=False):
             tie_break_ids=True, axis_sizes=(s,) if tree else None),
         mesh=mesh,
         in_specs=(P(SHARD_AXIS, None, None),) * 2,
-        out_specs=(P(None, None), P(None, None)), check_rep=False))
+        out_specs=(P(None, None), P(None, None)), check_vma=False))
     spec = NamedSharding(mesh, P(SHARD_AXIS, None, None))
     dd, ii = f(jax.device_put(jnp.asarray(d), spec),
                jax.device_put(jnp.asarray(i), spec))
@@ -174,12 +174,12 @@ def test_compressed_psum_accuracy():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed.collectives import compressed_psum
         mesh = jax.make_mesh((8,), ("x",))
         x = jax.random.normal(jax.random.PRNGKey(2), (8, 1000))
         f = shard_map(lambda s: compressed_psum(s[0], "x"), mesh=mesh,
-                      in_specs=P("x"), out_specs=P(None), check_rep=False)
+                      in_specs=P("x"), out_specs=P(None), check_vma=False)
         got, want = f(x), jnp.sum(x, axis=0)
         rel = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
         assert rel < 0.03, rel              # int8 quantisation error bound

@@ -152,6 +152,32 @@ def test_distance_topk_scales(metric):
     assert (np.sort(np.asarray(got_i)) == np.sort(np.asarray(exp_i))).all()
 
 
+@pytest.mark.parametrize("n_valid", [3, 150])
+def test_distance_topk_valid_mask(n_valid):
+    """A row mask (the free slots of a sharded block) is applied inside
+    the scan: masked rows never outrank a valid row, and kernel and
+    oracle agree. With fewer valid rows than k the tail is masked rows
+    at the padding distance."""
+    n, k = 300, 8
+    rng = np.random.default_rng(2)
+    db = jnp.asarray(rng.normal(size=(n, 32)).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(5, 32)).astype(np.float32))
+    valid = np.zeros(n, bool)
+    valid[rng.choice(n, n_valid, replace=False)] = True
+    pd, pi = distance_topk_pallas(db, q, k, valid=jnp.asarray(valid),
+                                  block_q=4, block_n=64, interpret=True)
+    neg, j = jax.lax.top_k(-pd, k)
+    got_d, got_i = np.asarray(-neg), np.asarray(
+        jnp.take_along_axis(pi, j, axis=1))
+    exp_d, exp_i = ref.distance_topk_ref(db, q, k, valid=jnp.asarray(valid))
+    live = min(k, n_valid)
+    assert valid[got_i[:, :live]].all()
+    assert (got_d[:, live:] >= 3.0e38).all()
+    np.testing.assert_allclose(got_d[:, :live], np.asarray(exp_d)[:, :live],
+                               rtol=1e-5, atol=1e-5)
+    assert (got_i[:, :live] == np.asarray(exp_i)[:, :live]).all()
+
+
 @pytest.mark.parametrize("metric", ["cosine", "l2"])
 def test_gather_distance_scales(metric):
     """int8 rows + per-row scale DMA: fused decode inside the wave loop
@@ -197,7 +223,7 @@ def test_flat_topk_scales_dispatch(monkeypatch):
     enc, scales = get_codec("int8").encode(
         rng.normal(size=(128, 32)).astype(np.float32))
     q = jnp.asarray(rng.normal(size=(4, 32)).astype(np.float32))
-    monkeypatch.setenv("REPRO_PALLAS", "off")
+    monkeypatch.delenv("REPRO_PALLAS", raising=False)
     d0, i0 = ops.flat_topk(jnp.asarray(enc), q, 5,
                            scales=jnp.asarray(scales))
     monkeypatch.setenv("REPRO_PALLAS", "interpret")
@@ -208,13 +234,108 @@ def test_flat_topk_scales_dispatch(monkeypatch):
 
 
 def test_ops_dispatch_matches_ref(monkeypatch):
-    """ops.* under REPRO_PALLAS=interpret must equal REPRO_PALLAS=off."""
+    """ops.* under REPRO_PALLAS=interpret must equal the reference (the
+    variable unset)."""
     from repro.kernels import ops
     db = jax.random.normal(jax.random.PRNGKey(0), (128, 32))
     q = jax.random.normal(jax.random.PRNGKey(1), (4, 32))
-    monkeypatch.setenv("REPRO_PALLAS", "off")
+    monkeypatch.delenv("REPRO_PALLAS", raising=False)
     d0, i0 = ops.flat_topk(db, q, 5)
     monkeypatch.setenv("REPRO_PALLAS", "interpret")
     d1, i1 = ops.flat_topk(db, q, 5)
     np.testing.assert_allclose(np.asarray(d0), np.asarray(d1), rtol=1e-5)
     assert (np.asarray(i0) == np.asarray(i1)).all()
+
+
+@pytest.mark.parametrize("kernel", ["flash_decode", "embedding_bag"])
+def test_interpret_none_resolves_like_retrieval_kernels(monkeypatch, kernel):
+    """flash_decode_pallas and embedding_bag_pallas default to
+    interpret=None and resolve it through ``resolve_interpret``, as the
+    retrieval kernels do: the interpreter off-TPU (env override honoured),
+    the compiled kernel on a TPU whatever the environment says."""
+    import repro.kernels as kpkg
+    from repro.kernels import embedding_bag as eb
+    from repro.kernels import flash_decode as fd
+
+    mod = fd if kernel == "flash_decode" else eb
+    seen = []
+    monkeypatch.setattr(mod, "_call", lambda *a: seen.append(a[-1]))
+
+    def call():
+        if kernel == "flash_decode":
+            z = jnp.zeros((1, 2, 8))
+            flash_decode_pallas(z, jnp.zeros((1, 8, 2, 8)),
+                                jnp.zeros((1, 8, 2, 8)), jnp.asarray(8))
+        else:
+            embedding_bag_pallas(jnp.zeros((4, 8)),
+                                 jnp.zeros((2, 3), jnp.int32))
+        return seen.pop()
+
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(kpkg, "on_tpu", lambda: False)
+    assert call() is True
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert call() is False
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(kpkg, "on_tpu", lambda: True)
+    assert call() is False
+
+
+def test_tpu_backend_ignores_env_switches(monkeypatch):
+    """On a TPU backend no environment variable routes an op to the jnp
+    reference or the interpreter: REPRO_PALLAS and REPRO_PALLAS_INTERPRET
+    only steer CPU runs."""
+    import repro.kernels as kpkg
+    from repro.kernels import ops, resolve_interpret
+
+    monkeypatch.setattr(kpkg, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.delenv("REPRO_PALLAS", raising=False)
+    assert ops._use_pallas() == (True, False)
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    assert ops._use_pallas() == (True, False)
+    for flag in ("1", "0"):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", flag)
+        assert resolve_interpret(None) is False
+    assert resolve_interpret(True) is True       # an explicit arg wins
+
+
+@pytest.mark.parametrize("n,w", [(1, 10), (37, 10), (1000, 4), (64, 32)])
+def test_packed_rows_take_and_set(n, w):
+    """PackedRows holds an [N, W] table in 128-lane rows: every row reads
+    back as stored, numpy and jax packing agree, and set_rows writes only
+    the rows it names."""
+    from repro.kernels.layout import pack_rows
+    rng = np.random.default_rng(n)
+    dense = rng.integers(-1, n, (n, w)).astype(np.int32)
+    p_np, p_jx = pack_rows(dense), pack_rows(jnp.asarray(dense))
+    assert p_np.table.shape == (-(-n // p_np.per), 128)
+    assert np.array_equal(p_np.table, np.asarray(p_jx.table))
+    packed = jax.tree.map(jnp.asarray, p_np)
+    assert np.array_equal(np.asarray(packed.take(jnp.arange(n))), dense)
+    rows = np.unique(rng.integers(0, n, max(n // 3, 1))).astype(np.int32)
+    new = rng.integers(-1, n, (len(rows), w)).astype(np.int32)
+    dense[rows] = new
+    got = packed.set_rows(jnp.asarray(rows), jnp.asarray(new))
+    assert np.array_equal(np.asarray(got.take(jnp.arange(n))), dense)
+    # the lanes past W keep the fill
+    tail = np.asarray(got.table).reshape(-1, got.lanes)[:, w:]
+    assert (tail == -1).all()
+
+
+def test_layout_views_are_bitcasts_at_device_capacity():
+    """At ``device_capacity`` rows the row-tile, lane-row and packed views
+    need no padding; the capacity rounds up to a multiple of 1024."""
+    from repro.kernels.layout import (device_capacity, lane_rows, pack_rows,
+                                      row_tiles)
+    assert [device_capacity(n) for n in (0, 1, 1024, 1025, 1_000_001)] == \
+        [1024, 1024, 1024, 2048, 1_000_448]
+    cap = device_capacity(3000)
+    v = jnp.arange(cap * 4, dtype=jnp.float32).reshape(cap, 4)
+    assert row_tiles(v).shape == (cap // 8, 8, 4)
+    assert np.array_equal(np.asarray(row_tiles(v)).reshape(cap, 4),
+                          np.asarray(v))
+    s = jnp.arange(cap, dtype=jnp.float32)
+    assert np.array_equal(np.asarray(lane_rows(s)).reshape(-1), np.asarray(s))
+    assert pack_rows(np.zeros((cap, 10), np.int32)).table.shape == \
+        (cap // 8, 128)

@@ -420,10 +420,68 @@ def test_exact_block_cache_invalidation():
 
 
 def test_quantize_slack_bounded():
-    """The compiled fan-out's cache key quantizes the dead-slot bound to
-    a power of two: O(log R) distinct values over any corpus growth, and
-    never below the true bound (under-fetch would drop candidates)."""
-    from repro.core.sharded import _quantize_slack
-    assert _quantize_slack(0) == 0
-    assert all(_quantize_slack(r) >= r for r in range(5000))
-    assert len({_quantize_slack(r) for r in range(5000)}) <= 15
+    """No shard over-fetches past k ("slack" is zero): free slots, more
+    than one 1024-row kernel tile of them in a shard, are masked inside
+    the compiled scan (REPRO_PALLAS=interpret runs the Pallas kernel).
+    The sharded flat index and the tenant slab search (S=1 and S=2 pools)
+    return the answers of an S=1 flat index, and the compiled fan-out is
+    reused across pack states with different dead-slot counts."""
+    out = run_sub("""
+        import os
+        os.environ["REPRO_PALLAS"] = "interpret"
+        import numpy as np
+        from repro.core import IndexPool, make_index, sharded
+        from repro.core.sharded import shard_of_key
+        from repro.data.synthetic import make_corpus
+        S, K = 2, 10
+        pool_keys = [f"d{i}" for i in range(8000)]
+        k0 = [k for k in pool_keys if shard_of_key(k, S) == 0][:1400]
+        k1 = [k for k in pool_keys if shard_of_key(k, S) == 1][:100]
+        more = [k for k in pool_keys if shard_of_key(k, S) == 1][100:300]
+        # rows in the positive orthant; queries near them, and queries in
+        # the negative orthant, whose every live cosine distance is above
+        # a free slot's zero row (1.0): an unmasked free slot would
+        # displace a live answer
+        data = np.abs(make_corpus(1700, 16, seed=0))
+        q = np.abs(make_corpus(8, 16, seed=2))
+        q[4:] *= -1
+
+        def same(a, b, tag):
+            ka, da = a
+            kb, db = b
+            assert ka == kb, tag
+            np.testing.assert_allclose(np.asarray(da), np.asarray(db),
+                                       rtol=1e-6, atol=1e-7, err_msg=tag)
+
+        # sharded flat: shard 1 holds 100 rows in a block of >= 1400
+        i1 = make_index("flat", dim=16, metric="cosine", n_shards=1)
+        iS = make_index("flat", dim=16, metric="cosine", n_shards=S)
+        for idx in (i1, iS):
+            idx.bulk_insert(k0 + k1, data[:1500])
+        same(i1.query_batch(q, K), iS.query_batch(q, K), "initial")
+        fn = sharded._fanout_topk_fn
+        size0 = fn.cache_info().currsize
+        steps = (("delete 200 in shard 0", lambda i: [i.delete(x) for x in k0[:200]]),
+                 ("insert 200 in shard 1", lambda i: i.bulk_insert(more, data[1500:])),
+                 ("delete 1100 in shard 0", lambda i: [i.delete(x) for x in k0[200:1300]]))
+        for tag, step in steps:
+            step(i1)
+            step(iS)
+            same(i1.query_batch(q, K), iS.query_batch(q, K), tag)
+        assert fn.cache_info().currsize == size0, fn.cache_info()
+
+        # tenant slab search: 2048-row slabs, most of each slot free
+        for n_shards in (1, S):
+            pool = IndexPool(dim=16, slab_rows=2048, n_shards=n_shards)
+            orc = make_index("flat", dim=16, metric="cosine")
+            for x in (pool, orc):
+                args = ("a",) if x is pool else ()
+                x.bulk_insert(*args, k0 + k1, data[:1500])
+            for kk in k0[:700]:
+                pool.delete("a", kk)
+                orc.delete(kk)
+            same(pool.query_batch("a", q, k=K), orc.query_batch(q, K),
+                 f"pool S={n_shards}")
+        print("OK")
+    """)
+    assert "OK" in out
