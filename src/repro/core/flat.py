@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.core.codec import (check_codec_arrays as _check_codec_arrays,
                               effective_rerank, get_codec)
+from repro.core.dispatch import span
 from repro.core.hnsw_build import normalize_rows
 from repro.core.index import VectorIndex
 from repro.core.sharded import ShardedRows
@@ -146,10 +147,12 @@ class FlatVectorIndex(VectorIndex):
             d, rows = self._rows.topk(q, k)
         else:
             _, cand = self._rows.topk(q, k * rf)
-            d, rows = self._rows.rerank_topk(q, cand, k)
-        keys = [[self._rows.key_of_row(int(r)) if r >= 0 else None
-                 for r in row] for row in rows]
-        return _pad_results(keys, d, k)
+            with span("index.rerank", rows=q.shape[0]):
+                d, rows = self._rows.rerank_topk(q, cand, k)
+        with span("index.keys", rows=q.shape[0]):
+            keys = [[self._rows.key_of_row(int(r)) if r >= 0 else None
+                     for r in row] for row in rows]
+            return _pad_results(keys, d, k)
 
     def exact_query(self, query, k: int = 10):
         return self.query(query, k)        # flat IS the brute-force oracle

@@ -467,12 +467,15 @@ def search_core(g: DeviceGraph, q: jax.Array, k: int, ef: int,
     if beam_impl not in ("fused", "jnp"):
         raise ValueError(f"unknown beam_impl {beam_impl!r}; "
                          "expected 'fused' or 'jnp'")
-    ep, ep_dist = descend(g, q)
-    if beam_impl == "fused":
-        beam_i, beam_d = _beam_search_fused(g, q, ep, ep_dist, ef,
-                                            max_iters, beam_expand)
-    else:
-        beam_i, beam_d = _beam_search(g, q, ep, ep_dist, ef, max_iters)
+    with jax.named_scope("hnsw.descent"):
+        ep, ep_dist = descend(g, q)
+    with jax.named_scope("hnsw.beam"):
+        if beam_impl == "fused":
+            beam_i, beam_d = _beam_search_fused(g, q, ep, ep_dist, ef,
+                                                max_iters, beam_expand)
+        else:
+            beam_i, beam_d = _beam_search(g, q, ep, ep_dist, ef,
+                                          max_iters)
     # tombstone filter: deleted rows were traversable during the beam search
     # but must not be returned (DESIGN.md §3)
     dead = jnp.take(g.deleted, jnp.clip(beam_i, 0, g.n - 1)) | (beam_i < 0)
@@ -502,7 +505,6 @@ def search_graph(g: DeviceGraph, queries, k: int = 10, ef: int = 64,
     dispatches on the jnp path."""
     q = _prep_queries(g, queries)
     ef = max(ef, k)
-    dispatch.bump("hnsw.search_graph")
     dispatch.bump("hnsw.beam_launches",
                   dispatch.beam_launches(beam_impl, ef, max_iters))
     return _search_jit(g, q, k, ef, max_iters, beam_impl, beam_expand)

@@ -49,6 +49,7 @@ from repro.core import hnsw_build as build
 from repro.core import stacked as jstacked
 from repro.core.codec import (check_codec_arrays as _check_codec_arrays,
                               effective_rerank, get_codec, rerank_exact)
+from repro.core.dispatch import span
 from repro.core.flat import FlatIndex
 from repro.core.hnsw_build import normalize_rows
 from repro.core.index import VectorIndex
@@ -401,19 +402,25 @@ class HNSW(VectorIndex):
             raise ValueError(f"query_batch expects [B, D], got {q.shape}")
         if self.n_shards > 1:
             return self._query_batch_sharded(q, k, ef)
+        rows = q.shape[0]
         rf = effective_rerank(self._codec, self.rerank_factor)
-        ids, dists = jhnsw.search_graph(self._dg(), q, k=k * rf,
-                                        ef=ef or self.ef_search,
-                                        beam_impl=self.beam_impl)
-        ids, dists = np.asarray(ids), np.asarray(dists)
+        with span("index.scan", rows=rows):
+            ids, dists = jhnsw.search_graph(self._dg(), q, k=k * rf,
+                                            ef=ef or self.ef_search,
+                                            beam_impl=self.beam_impl)
+        with span("index.fetch", rows=rows):
+            ids, dists = np.asarray(ids), np.asarray(dists)
         if rf > 1:
             # over-fetched beam candidates rerank exactly in fp32 against
             # the canonical host rows (§9); beam already dropped
             # tombstoned ids, so every candidate is live
-            n = self._builder.n
-            dists, ids = rerank_exact(self._builder.vectors[:n], q, ids, k,
-                                      metric=self.metric)
-        keys = [[self._keys[i] if i >= 0 else None for i in row] for row in ids]
+            with span("index.rerank", rows=rows):
+                n = self._builder.n
+                dists, ids = rerank_exact(self._builder.vectors[:n], q, ids,
+                                          k, metric=self.metric)
+        with span("index.keys", rows=rows):
+            keys = [[self._keys[i] if i >= 0 else None for i in row]
+                    for row in ids]
         return keys, dists
 
     def _drop_derived(self) -> None:
@@ -463,18 +470,24 @@ class HNSW(VectorIndex):
         ``k * rerank_factor`` per shard, merge in-program, and rerank
         the merged candidates exactly in fp32 against the gid-aligned
         canonical rows."""
-        st = self._stacked()
+        rows = q.shape[0]
         rf = effective_rerank(self._codec, self.rerank_factor)
         kf = k * rf
-        d, gid = jstacked.search_stacked(st, q, kf,
-                                         max(ef or self.ef_search, kf),
-                                         beam_impl=self.beam_impl)
+        with span("index.scan", rows=rows):
+            st = self._stacked()
+            d, gid = jstacked.search_stacked(st, q, kf,
+                                             max(ef or self.ef_search, kf),
+                                             beam_impl=self.beam_impl)
+        with span("index.fetch", rows=rows):
+            d, gid = np.asarray(d), np.asarray(gid)
         if rf > 1:
-            d, gid = rerank_exact(self._rerank_rows(st), q, gid, k,
-                                  metric=self.metric)
+            with span("index.rerank", rows=rows):
+                d, gid = rerank_exact(self._rerank_rows(st), q, gid, k,
+                                      metric=self.metric)
         cap = st.cap
-        keys = [[self._shards[int(g) // cap]._keys[int(g) % cap]
-                 if g >= 0 else None for g in row] for row in gid]
+        with span("index.keys", rows=rows):
+            keys = [[self._shards[int(g) // cap]._keys[int(g) % cap]
+                     if g >= 0 else None for g in row] for row in gid]
         return keys, d
 
     def _query_batch_sharded_loop(self, q: np.ndarray, k: int,

@@ -46,6 +46,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.codec import VectorCodec, get_codec, rerank_exact
+from repro.core.dispatch import span
 from repro.core.hnsw_build import normalize_rows
 from repro.distributed.collectives import hierarchical_topk
 from repro.kernels import ops
@@ -646,22 +647,27 @@ class ShardedRows:
         columns with missing slots as (INF, -1).
         """
         q = np.asarray(queries, np.float32)
+        rows = q.shape[0]
         if self.n_shards == 1:
-            flat = self.pack()
-            d, i = flat.query(q, min(k, flat.n))
-            d, i = np.asarray(d), np.asarray(i)
-            return d, self._live_rows[i]
-        mesh, blocks, gids, scl = self.pack()
-        qj = jnp.asarray(q)
-        if self.metric == "cosine" and self.normalize_on_pack:
-            qj = qj / jnp.maximum(
-                jnp.linalg.norm(qj, axis=-1, keepdims=True), 1e-12)
-        fn = _fanout_topk_fn(mesh, k, self.metric,
-                             has_scales=scl is not None,
-                             wire_bf16=resolve_wire_bf16(self.wire_bf16))
-        d, g = (fn(blocks, gids, scl, qj) if scl is not None
-                else fn(blocks, gids, qj))
-        return np.asarray(d), np.asarray(g)
+            with span("index.scan", rows=rows):
+                flat = self.pack()
+                d, i = flat.query(q, min(k, flat.n))
+            with span("index.fetch", rows=rows):
+                d, i = np.asarray(d), np.asarray(i)
+                return d, self._live_rows[i]
+        with span("index.scan", rows=rows):
+            mesh, blocks, gids, scl = self.pack()
+            qj = jnp.asarray(q)
+            if self.metric == "cosine" and self.normalize_on_pack:
+                qj = qj / jnp.maximum(
+                    jnp.linalg.norm(qj, axis=-1, keepdims=True), 1e-12)
+            wire = resolve_wire_bf16(self.wire_bf16)
+            fn = _fanout_topk_fn(mesh, k, self.metric,
+                                 has_scales=scl is not None, wire_bf16=wire)
+            d, g = (fn(blocks, gids, scl, qj) if scl is not None
+                    else fn(blocks, gids, qj))
+        with span("index.fetch", rows=rows):
+            return np.asarray(d), np.asarray(g)
 
     def rerank_topk(self, queries: np.ndarray, gids: np.ndarray, k: int
                     ) -> tuple[np.ndarray, np.ndarray]:

@@ -41,13 +41,6 @@ from repro.kernels.layout import LANES, PackedRows
 
 INF = np.float32(3e38)
 
-# incremented once per compiled stacked-search invocation: tests assert
-# a sharded ``query_batch`` is exactly ONE device dispatch at any S.
-# Kept as the historical module global; the named counters in
-# core/dispatch.py ("stacked.search_stacked", "stacked.beam_launches")
-# are bumped in lockstep.
-DISPATCH_COUNT = 0
-
 
 @dataclasses.dataclass(frozen=True)
 class StackedGraphs:
@@ -189,15 +182,15 @@ def _stacked_search_fn(mesh: Mesh, k: int, ef: int, metric: str,
 def search_stacked(st: StackedGraphs, queries, k: int, ef: int,
                    wire_bf16: bool | None = None,
                    beam_impl: str = "fused"
-                   ) -> tuple[np.ndarray, np.ndarray]:
+                   ) -> tuple[jax.Array, jax.Array]:
     """Batched k-NN over a stacked segment set: queries [B, D] ->
-    (dists [B, k], gids [B, k]), missing slots (INF, -1). One compiled
-    dispatch regardless of shard count; the only per-query host->device
-    movement is the query batch itself. ``beam_impl`` selects each
-    shard's layer-0 beam (fused one-launch kernel vs jnp reference) —
-    the same kernel rides under shard_map, so the fan-out stays a
-    single dispatch either way."""
-    global DISPATCH_COUNT
+    device (dists [B, k], gids [B, k]), missing slots (INF, -1). One
+    compiled dispatch regardless of shard count (the
+    ``stacked.search_stacked`` counter, core/dispatch.py); the only
+    per-query host->device movement is the query batch itself.
+    ``beam_impl`` selects each shard's layer-0 beam (fused one-launch
+    kernel vs jnp reference) — the same kernel rides under shard_map,
+    so the fan-out stays a single dispatch either way."""
     q = jnp.asarray(queries, jnp.float32)
     if st.metric == "cosine":
         q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True),
@@ -206,14 +199,9 @@ def search_stacked(st: StackedGraphs, queries, k: int, ef: int,
                             st.max_level, st.scales is not None,
                             resolve_wire_bf16(wire_bf16), beam_impl, st.m2,
                             st.m)
-    DISPATCH_COUNT += 1
     dispatch.bump("stacked.search_stacked")
-    dispatch.bump("stacked.beam_launches",
-                  dispatch.beam_launches(beam_impl, max(ef, k)))
     if st.scales is not None:
-        d, gid = fn(st.vectors, st.neighbors0, st.upper, st.levels,
-                    st.entry, st.deleted, st.scales, q)
-    else:
-        d, gid = fn(st.vectors, st.neighbors0, st.upper, st.levels,
-                    st.entry, st.deleted, q)
-    return np.asarray(d), np.asarray(gid)
+        return fn(st.vectors, st.neighbors0, st.upper, st.levels,
+                  st.entry, st.deleted, st.scales, q)
+    return fn(st.vectors, st.neighbors0, st.upper, st.levels,
+              st.entry, st.deleted, q)

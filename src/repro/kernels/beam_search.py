@@ -340,6 +340,7 @@ def _call(vectors, nbr, q, ep, ep_dist, scales, metric, ef,
                    jax.ShapeDtypeStruct((pb, width), jnp.float32)),
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name="beam_search",
     )(*args)
     return ids[:b, :ef], dists[:b, :ef]
 

@@ -168,9 +168,11 @@ def _call(db, q, scales, valid, k, metric, block_q, block_n, interpret):
             jax.ShapeDtypeStruct((tiles, pb, k), jnp.int32),
         ],
         interpret=interpret,
+        name="distance_topk",
     )(*args)
-    dists = jnp.transpose(dists[:, :b], (1, 0, 2)).reshape(b, tiles * k)
-    ids = jnp.transpose(ids[:, :b], (1, 0, 2)).reshape(b, tiles * k)
+    with jax.named_scope("flat.merge"):       # [T, B, k] -> [B, T*k]
+        dists = jnp.transpose(dists[:, :b], (1, 0, 2)).reshape(b, tiles * k)
+        ids = jnp.transpose(ids[:, :b], (1, 0, 2)).reshape(b, tiles * k)
     return dists, ids
 
 

@@ -140,4 +140,5 @@ def _call(table, ids, weights, combine, block_b, wave, interpret):
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        name="embedding_bag",
     )(ids, weights, table)

@@ -132,4 +132,5 @@ def _call(q, k, v, cur_len, block_s, interpret):
             pltpu.VMEM((h, dh), jnp.float32),      # acc
         ],
         interpret=interpret,
+        name="flash_decode",
     )(cur, q, k, v)

@@ -157,6 +157,7 @@ def _call(vectors, q, ids, scales, metric, block_q, interpret):
         scratch_shapes=[pltpu.VMEM((2 * k, ROW_TILE, d), vectors.dtype),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
+        name="gather_distance",
     )(*args)
     return out[:b]
 

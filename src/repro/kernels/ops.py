@@ -114,8 +114,9 @@ def flat_topk(db: jax.Array, q: jax.Array, k: int,
         pd, pi = distance_topk_pallas(db, q, k, metric=metric,
                                       scales=scales, valid=valid,
                                       interpret=interp)
-        neg, j = jax.lax.top_k(-pd, k)                 # tiny [B, T*k] merge
-        return -neg, jnp.take_along_axis(pi, j, axis=1)
+        with jax.named_scope("flat.merge"):        # tiny [B, T*k] merge
+            neg, j = jax.lax.top_k(-pd, k)
+            return -neg, jnp.take_along_axis(pi, j, axis=1)
     return _ref.distance_topk_ref(db, q, k, metric=metric, scales=scales,
                                   valid=valid)
 

@@ -69,6 +69,7 @@ import hashlib
 
 import numpy as np
 
+from repro.core.dispatch import span
 from repro.core.index import VectorIndex
 
 # Bucket ladder: pending batches are padded up to the next power of two so
@@ -255,27 +256,28 @@ class RetrievalEngine:
         """
         if not self.queue:
             return 0
-        self._check_epoch()
-        pending, self.queue = list(self.queue), collections.deque()
-        groups: dict[tuple, list[RetrievalRequest]] = {}
-        followers: dict[tuple, list[RetrievalRequest]] = {}  # ck -> dups
-        leaders: dict[tuple, RetrievalRequest] = {}
-        done = 0
-        for r in pending:
-            r._ck = ck = self._cache_key(r)
-            hit = self._cache_get(ck)
-            if hit is not None:
-                r.keys, r.dists = list(hit[0]), hit[1].copy()
-                r.from_cache = r.done = True
-                self.stats.cache_hits += 1
-                done += 1
-            elif ck in leaders:
-                followers.setdefault(ck, []).append(r)
-                self.stats.dedup_hits += 1
-            else:
-                leaders[ck] = r
-                self.stats.cache_misses += 1
-                groups.setdefault((r.k, r.ef), []).append(r)
+        with span("engine.coalesce", tick=self.stats.ticks):
+            self._check_epoch()
+            pending, self.queue = list(self.queue), collections.deque()
+            groups: dict[tuple, list[RetrievalRequest]] = {}
+            followers: dict[tuple, list[RetrievalRequest]] = {}  # ck->dups
+            leaders: dict[tuple, RetrievalRequest] = {}
+            done = 0
+            for r in pending:
+                r._ck = ck = self._cache_key(r)
+                hit = self._cache_get(ck)
+                if hit is not None:
+                    r.keys, r.dists = list(hit[0]), hit[1].copy()
+                    r.from_cache = r.done = True
+                    self.stats.cache_hits += 1
+                    done += 1
+                elif ck in leaders:
+                    followers.setdefault(ck, []).append(r)
+                    self.stats.dedup_hits += 1
+                else:
+                    leaders[ck] = r
+                    self.stats.cache_misses += 1
+                    groups.setdefault((r.k, r.ef), []).append(r)
         first_err: Exception | None = None
         for (k, ef), reqs in groups.items():
             for lo in range(0, len(reqs), self.max_batch):
@@ -287,16 +289,19 @@ class RetrievalEngine:
                         r.error, r.done = e, True
                         done += 1
                     first_err = first_err or e
-        for ck, dups in followers.items():
-            leader = leaders[ck]
-            for r in dups:
-                if leader.error is not None:
-                    r.error = leader.error
-                else:
-                    r.keys, r.dists = list(leader.keys), leader.dists.copy()
-                    r.from_cache = True
-                r.done = True
-                done += 1
+        if followers:
+            with span("engine.fanout"):
+                for ck, dups in followers.items():
+                    leader = leaders[ck]
+                    for r in dups:
+                        if leader.error is not None:
+                            r.error = leader.error
+                        else:
+                            r.keys = list(leader.keys)
+                            r.dists = leader.dists.copy()
+                            r.from_cache = True
+                        r.done = True
+                        done += 1
         self.stats.ticks += 1
         if first_err is not None:
             raise first_err
@@ -308,36 +313,41 @@ class RetrievalEngine:
         the rows back out to the callers and into the cache."""
         n = len(reqs)
         bucket = bucket_size(n, self.max_batch)
-        q = np.stack([r.query for r in reqs])
-        if bucket > n:
-            # pad by repeating row 0: numerically benign, result rows are
-            # sliced off below, and the compiled shape stays on the ladder
-            q = np.concatenate([q, np.repeat(q[:1], bucket - n, axis=0)])
-        kw = {} if ef is None else {"ef": ef}
-        if self._multi:
-            # cross-tenant coalescing (DESIGN.md §10): the whole group —
-            # rows of DIFFERENT tenants — goes down as one dispatch;
-            # padding rows replicate row 0's tenant along with its query
-            tenants = [r.tenant for r in reqs] \
-                + [reqs[0].tenant] * (bucket - n)
-            keys, dists = self.index.query_batch_multi(q, tenants, k=k,
-                                                       **kw)
-        else:
-            keys, dists = self.index.query_batch(q, k=k, **kw)
-        dists = np.asarray(dists)
-        self.stats.searches += 1
-        self.stats.searched_queries += n
-        self.stats.padded_queries += bucket - n
-        for r, row_keys, row_d in zip(reqs, keys, dists):
-            r.keys, r.dists = list(row_keys), np.asarray(row_d)
-            r.done = True
-            self._cache_put(r._ck, r.keys, r.dists)
+        with span("engine.dispatch", rows=n, bucket=bucket):
+            q = np.stack([r.query for r in reqs])
+            if bucket > n:
+                # pad by repeating row 0: numerically benign, result rows
+                # are sliced off below, and the compiled shape stays on
+                # the ladder
+                q = np.concatenate([q, np.repeat(q[:1], bucket - n,
+                                                 axis=0)])
+            kw = {} if ef is None else {"ef": ef}
             if self._multi:
-                # record validity at store time: mutations cannot
-                # interleave mid-tick (single-threaded), so the tenant's
-                # current epoch IS the epoch the search ran at
-                self._tenant_epochs.setdefault(r.tenant,
-                                               self.index.epoch(r.tenant))
+                # cross-tenant coalescing (DESIGN.md §10): the whole
+                # group — rows of DIFFERENT tenants — goes down as one
+                # dispatch; padding rows replicate row 0's tenant along
+                # with its query
+                tenants = [r.tenant for r in reqs] \
+                    + [reqs[0].tenant] * (bucket - n)
+                keys, dists = self.index.query_batch_multi(q, tenants,
+                                                           k=k, **kw)
+            else:
+                keys, dists = self.index.query_batch(q, k=k, **kw)
+        with span("engine.fanout", rows=n):
+            dists = np.asarray(dists)
+            self.stats.searches += 1
+            self.stats.searched_queries += n
+            self.stats.padded_queries += bucket - n
+            for r, row_keys, row_d in zip(reqs, keys, dists):
+                r.keys, r.dists = list(row_keys), np.asarray(row_d)
+                r.done = True
+                self._cache_put(r._ck, r.keys, r.dists)
+                if self._multi:
+                    # record validity at store time: mutations cannot
+                    # interleave mid-tick (single-threaded), so the
+                    # tenant's current epoch IS the epoch the search ran at
+                    self._tenant_epochs.setdefault(
+                        r.tenant, self.index.epoch(r.tenant))
         return n
 
     # ---------------------------------------------------------- frontends

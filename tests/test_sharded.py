@@ -349,7 +349,7 @@ def test_stacked_fanout_matches_loop_bitwise():
     shard count — the ISSUE 6 acceptance assert."""
     out = run_sub("""
         import numpy as np
-        from repro.core import make_index, stacked
+        from repro.core import dispatch, make_index
         from repro.data.synthetic import make_corpus
         data = make_corpus(250, 16, seed=0)
         keys = [f"d{i}" for i in range(250)]
@@ -361,9 +361,10 @@ def test_stacked_fanout_matches_loop_bitwise():
                                  n_shards=s)
                 idx.bulk_insert(keys, data)
                 idx.delete("d11")        # tombstones flow into the stack
-                before = stacked.DISPATCH_COUNT
+                before = dispatch.get("stacked.search_stacked")
                 kq, dq = idx.query_batch(q, 5)
-                assert stacked.DISPATCH_COUNT == before + 1, (kind, s)
+                assert dispatch.get("stacked.search_stacked") == before + 1, \
+                    (kind, s)
                 kl, dl = idx._query_batch_sharded_loop(q, 5, 48)
                 assert kq == kl, (kind, s)
                 np.testing.assert_allclose(np.asarray(dq),
@@ -371,9 +372,10 @@ def test_stacked_fanout_matches_loop_bitwise():
                                            rtol=0, atol=2.5e-7)
                 assert all("d11" not in row for row in kq)
                 # warm path: still exactly one dispatch, nothing rebuilt
-                before = stacked.DISPATCH_COUNT
+                before = dispatch.get("stacked.search_stacked")
                 idx.query_batch(q, 5)
-                assert stacked.DISPATCH_COUNT == before + 1, (kind, s)
+                assert dispatch.get("stacked.search_stacked") == before + 1, \
+                    (kind, s)
         print("OK")
     """)
     assert "OK" in out

@@ -16,6 +16,7 @@ one process may hold the TPU library, and the test workers each import
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +120,17 @@ CASES = {
     "flash_decode_serve": _flash,
 }
 
+# the name each case's kernel is given (``pallas_call(name=...)``): the
+# compiled custom call carries it as its instruction name, which is how
+# a device trace tells the kernels apart
+KERNEL = {"distance_topk_f32": "distance_topk",
+          "distance_topk_int8": "distance_topk",
+          "gather_distance": "gather_distance",
+          "beam_b1_ef64": "beam_search", "beam_b8_ef64": "beam_search",
+          "beam_b128_ef64_int8": "beam_search",
+          "beam_b1024_ef20_ingest": "beam_search",
+          "flash_decode_serve": "flash_decode"}
+
 
 @pytest.mark.parametrize("name", list(CASES))
 def test_kernel_compiles_for_v5e(one_chip, name):
@@ -127,7 +139,10 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
     fn, args = CASES[name](shape)
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{KERNEL[name]}(\.\d+)? = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text), name
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
