@@ -16,6 +16,11 @@ them:
   ``stacked.search_stacked`` compiled dispatches of the sharded graph
                             search (one per ``query_batch`` at any S):
                             tests/test_sharded.py
+  ``distance_topk.passes``  extraction passes of the flat scan kernel,
+                            summed over its (query tile, db tile) steps,
+                            when ``distance_topk_passes`` runs (never on
+                            the search path): tests/test_kernels.py and
+                            the pass measurement in PERF.md
 
 Spans are ``jax.profiler.TraceAnnotation``s on the profiler's own
 clock: free of cost beyond a context manager when no trace is taken.

@@ -103,20 +103,20 @@ def flat_topk(db: jax.Array, q: jax.Array, k: int,
               scales: jax.Array | None = None,
               valid: jax.Array | None = None
               ) -> tuple[jax.Array, jax.Array]:
-    """Exact k-NN: db [N,D], q [B,D] -> (dists [B,k], ids [B,k]).
+    """Exact k-NN: db [N,D], q [B,D] -> (dists [B,k], ids [B,k]),
+    ascending by (distance, id) as ``lax.top_k`` orders them.
 
     ``db`` may be codec-encoded (f32 / bf16 / int8, DESIGN.md §9);
     ``scales`` [N] fuses the per-row decode into the distance. Rows
-    where ``valid`` [N] is False never outrank a valid row."""
+    where ``valid`` [N] is False never outrank a valid row. On a TPU one
+    jitted call runs the scan kernel, which carries a running top-k
+    across the db tiles, and the sort of its [B, k] result: there is no
+    merge of per-tile partials."""
     use, interp = _use_pallas()
     if use:
         from repro.kernels.distance_topk import distance_topk_pallas
-        pd, pi = distance_topk_pallas(db, q, k, metric=metric,
-                                      scales=scales, valid=valid,
-                                      interpret=interp)
-        with jax.named_scope("flat.merge"):        # tiny [B, T*k] merge
-            neg, j = jax.lax.top_k(-pd, k)
-            return -neg, jnp.take_along_axis(pi, j, axis=1)
+        return distance_topk_pallas(db, q, k, metric=metric, scales=scales,
+                                    valid=valid, interpret=interp)
     return _ref.distance_topk_ref(db, q, k, metric=metric, scales=scales,
                                   valid=valid)
 

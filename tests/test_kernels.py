@@ -178,6 +178,97 @@ def test_distance_topk_valid_mask(n_valid):
     assert (got_i[:, :live] == np.asarray(exp_i)[:, :live]).all()
 
 
+def _running_case(case, metric, rng):
+    """-> (db [N,D] f32, q [B,D], k, block_q, block_n, valid or None)."""
+    if case == "worst":
+        # every db tile beats every query row's running k-th k times:
+        # distances fall strictly along the db for every query
+        n, b = 320, 6
+        u = rng.normal(size=32)
+        u /= np.linalg.norm(u)
+        noise = 0.001 * rng.normal(size=(n, 32))
+        if metric == "cosine":      # d = 1 - c (q.u), c rising
+            db = np.linspace(0.1, 10.0, n)[:, None] * u + noise
+            q = u + 0.001 * rng.normal(size=(b, 32))
+        else:                       # |q - x|^2 ~ r^2, r falling
+            db = np.linspace(40.0, 1.0, n)[:, None] * u + noise
+            q = 0.001 * rng.normal(size=(b, 32))
+        return db, q, 8, 4, 64, None
+    n, b, k, bq, bn = {"plain": (300, 8, 6, 8, 64),
+                       "k_eq_block_n": (200, 5, 32, 8, 32),
+                       "ragged_b": (300, 10, 6, 4, 64),
+                       "sparse_head": (300, 7, 8, 4, 64)}[case]
+    db = rng.normal(size=(n, 32))
+    q = rng.normal(size=(b, 32))
+    valid = None
+    if case == "sparse_head":
+        # the first two tiles hold 3 live rows between them, fewer than k
+        valid = np.ones(n, bool)
+        valid[:128] = False
+        valid[[5, 70, 127]] = True
+    return db, q, k, bq, bn, valid
+
+
+@pytest.mark.parametrize("case", ["plain", "k_eq_block_n", "ragged_b",
+                                  "sparse_head", "worst"])
+@pytest.mark.parametrize("codec", ["fp32", "int8"])
+@pytest.mark.parametrize("metric", ["cosine", "l2"])
+def test_distance_topk_running(case, codec, metric):
+    """The running top-k carried across db tiles is the exact top-k, in
+    ``lax.top_k``'s order: ids equal the oracle's slot for slot, with the
+    first tiles short of live rows, k == block_n, a query batch that is
+    not a block multiple, and a db on which every tile improves every
+    row (every tile runs all k passes)."""
+    from repro.core.codec import get_codec
+
+    rng = np.random.default_rng(7)
+    db, q, k, bq, bn, valid = _running_case(case, metric, rng)
+    db, q = db.astype(np.float32), jnp.asarray(q.astype(np.float32))
+    scales = None
+    if codec == "int8":
+        db, s = get_codec("int8").encode(db)
+        scales = jnp.asarray(s)
+    vj = None if valid is None else jnp.asarray(valid)
+    got_d, got_i = distance_topk_pallas(jnp.asarray(db), q, k, metric=metric,
+                                        scales=scales, valid=vj, block_q=bq,
+                                        block_n=bn, interpret=True)
+    exp_d, exp_i = ref.distance_topk_ref(jnp.asarray(db), q, k,
+                                         metric=metric, scales=scales,
+                                         valid=vj)
+    assert got_d.shape == got_i.shape == (q.shape[0], k)
+    np.testing.assert_allclose(np.asarray(got_d), np.asarray(exp_d),
+                               rtol=1e-5, atol=1e-5)
+    assert (np.asarray(got_i) == np.asarray(exp_i)).all()
+
+
+def test_distance_topk_passes_follow_the_data():
+    """The pass counter: the first tile fills the running top-k in k
+    passes; over a db sorted by ascending distance to the one query each
+    later tile runs one pass that finds nothing to enter, and over the
+    same db sorted by descending distance every tile runs k. The
+    ``distance_topk.passes`` counter adds up the passes of each call."""
+    from repro.core import dispatch
+    from repro.kernels.distance_topk import distance_topk_passes
+
+    rng = np.random.default_rng(3)
+    n, k, bn = 512, 8, 64
+    db = rng.normal(size=(n, 32)).astype(np.float32)
+    q = rng.normal(size=(1, 32)).astype(np.float32)
+    order = np.argsort(((db - q) ** 2).sum(1), kind="stable")
+    tiles = n // bn
+    for rows, want in [(order, [k] + [1] * (tiles - 1)),
+                       (order[::-1], [k] * tiles)]:
+        before = dispatch.get("distance_topk.passes")
+        d, i, passes = distance_topk_passes(jnp.asarray(db[rows]),
+                                            jnp.asarray(q), k, metric="l2",
+                                            block_n=bn, interpret=True)
+        assert np.asarray(passes).tolist() == [want]
+        assert dispatch.get("distance_topk.passes") - before == sum(want)
+        exp_d, exp_i = ref.distance_topk_ref(jnp.asarray(db[rows]),
+                                             jnp.asarray(q), k, metric="l2")
+        assert (np.asarray(i) == np.asarray(exp_i)).all()
+
+
 @pytest.mark.parametrize("metric", ["cosine", "l2"])
 def test_gather_distance_scales(metric):
     """int8 rows + per-row scale DMA: fused decode inside the wave loop

@@ -7,16 +7,20 @@ layer-0 neighbors, ef 64 for queries and 20 for bulk ingest) and the
 flash-decode shape the serving launcher runs; the beam kernel reads the
 adjacency packed, as the resident graph stores it. The last cases compile
 the whole HNSW search over the device graph of a host graph whose row
-count is not a multiple of 8. A refusal here is what the chip would
-raise. Nothing runs, so nothing here says anything about
+count is not a multiple of 8. The scan kernel's compiled op must also
+keep the signature the benchmark's roofline reader looks for
+(``bench/metrics/distance_topk_roofline.py``). A refusal here is what the
+chip would raise. Nothing runs, so nothing here says anything about
 results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and the test workers each import
 this file.
 """
+import ast
 import os
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +35,7 @@ from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.gather_distance import gather_distance_pallas
 from repro.kernels.layout import PackedRows, device_capacity
 
+ROOT = Path(__file__).resolve().parents[1]
 N, D = 1_000_000, 384
 N_ODD = N + 1                   # a host graph of N % 8 != 0 rows
 HBM_BYTES = 16 * 10**9          # one v5e chip
@@ -77,18 +82,18 @@ def _beam(b, ef, dtype=jnp.float32):
     return case
 
 
-def _topk(dtype):
+def _topk(dtype, k=10):
     def case(s):
         args = [s((N, D), dtype), s((128, D), jnp.float32)]
         if dtype == jnp.int8:
             args.append(s((N,), jnp.float32))
 
             def fn(db, q, sc):
-                return distance_topk_pallas(db, q, 10, scales=sc,
+                return distance_topk_pallas(db, q, k, scales=sc,
                                             interpret=False)
         else:
             def fn(db, q):
-                return distance_topk_pallas(db, q, 10, interpret=False)
+                return distance_topk_pallas(db, q, k, interpret=False)
         return fn, args
     return case
 
@@ -112,6 +117,8 @@ def _flash(s):
 CASES = {
     "distance_topk_f32": _topk(jnp.float32),
     "distance_topk_int8": _topk(jnp.int8),
+    # the benchmark's flat cell: k=10 times the int8 rerank factor of 4
+    "distance_topk_int8_k40": _topk(jnp.int8, k=40),
     "gather_distance": _gather,
     "beam_b1_ef64": _beam(1, 64),
     "beam_b8_ef64": _beam(8, 64),
@@ -125,11 +132,24 @@ CASES = {
 # a device trace tells the kernels apart
 KERNEL = {"distance_topk_f32": "distance_topk",
           "distance_topk_int8": "distance_topk",
+          "distance_topk_int8_k40": "distance_topk",
           "gather_distance": "gather_distance",
           "beam_b1_ef64": "beam_search", "beam_b8_ef64": "beam_search",
           "beam_b128_ef64_int8": "beam_search",
           "beam_b1024_ef20_ingest": "beam_search",
           "flash_decode_serve": "flash_decode"}
+
+
+def _roofline_signature() -> str:
+    """The ``SIGNATURE`` regex of bench/metrics/distance_topk_roofline.py,
+    read from its source (the benchmark's reader is not imported)."""
+    tree = ast.parse((ROOT / "bench" / "metrics"
+                      / "distance_topk_roofline.py").read_text())
+    for node in tree.body:
+        if (isinstance(node, ast.Assign)
+                and getattr(node.targets[0], "id", None) == "SIGNATURE"):
+            return ast.literal_eval(node.value)
+    raise AssertionError("no SIGNATURE in distance_topk_roofline.py")
 
 
 @pytest.mark.parametrize("name", list(CASES))
@@ -141,8 +161,13 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert re.search(rf"%{KERNEL[name]}(\.\d+)? = [^\n]*"
-                     r'custom_call_target="tpu_custom_call"', text), name
+    call = re.search(rf"%{KERNEL[name]}(\.\d+)? = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text)
+    assert call, name
+    if KERNEL[name] == "distance_topk":
+        # the benchmark's roofline reader finds the scan kernel by this
+        # signature: two rank-3 outputs, f32 and s32
+        assert re.search(_roofline_signature(), call.group(0)), call.group(0)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
