@@ -41,7 +41,9 @@ sits inside a per-row or per-request loop. ``SPANS`` lists every name:
   ``index.fetch``       the host blocked on the device results, then D2H
                         (and on one shard the map from packed to stored
                         rows)
-  ``index.rerank``      the exact fp32 rerank of a lossy codec
+  ``index.rerank``      the exact fp32 rerank of a lossy codec's
+                        over-fetch (HNSW; flat only over the bf16
+                        wire)
   ``index.keys``        row ids to keys, padding to k
 """
 from __future__ import annotations

@@ -84,9 +84,12 @@ class FlatVectorIndex(VectorIndex):
     block(s) stale and the next query re-packs once (DESIGN.md §8).
 
     ``dtype`` picks the row codec (fp32 | bf16 | int8, DESIGN.md §9):
-    device blocks and snapshot pages hold the encoded rows; lossy
-    searches run the asymmetric scan, over-fetch ``k·rerank_factor``
-    candidates, and rerank exactly in fp32 from the canonical host rows.
+    device blocks and snapshot pages hold the encoded rows, and lossy
+    searches run the asymmetric scan. The scan's float32 distances over
+    the stored rows are the result: the host rows are their decode, so a
+    re-score could only repeat them. ``rerank_factor`` applies only where
+    the fan-out merges over the bf16 wire (S > 1), whose order the
+    fp32 rerank restores.
     """
 
     kind = "flat"
@@ -136,14 +139,15 @@ class FlatVectorIndex(VectorIndex):
         """ONE sharded device dispatch for the whole [B, D] batch: every
         shard scans its own rows, per-shard top-k merges through the
         hierarchical tree (exact top-k either way). Under a lossy codec
-        the scan is asymmetric (fp32 query vs encoded rows), over-fetches
-        ``k·rerank_factor`` candidates, and reranks exactly in fp32 from
-        the canonical host rows (DESIGN.md §9)."""
+        the scan is asymmetric (fp32 query vs encoded rows). Only a bf16
+        wire merge loses float32 order: then the scan over-fetches
+        ``k·rerank_factor`` candidates and reranks them in fp32 from the
+        canonical host rows (DESIGN.md §9)."""
         q = np.asarray(queries, np.float32)
         if q.ndim != 2:
             raise ValueError(f"query_batch expects [B, D], got {q.shape}")
         rf = effective_rerank(self._codec, self.rerank_factor)
-        if rf <= 1:
+        if rf <= 1 or self._rows.exact_distances:
             d, rows = self._rows.topk(q, k)
         else:
             _, cand = self._rows.topk(q, k * rf)

@@ -347,6 +347,13 @@ class ShardedRows:
         return self._alive
 
     @property
+    def exact_distances(self) -> bool:
+        """Whether ``topk`` returns the float32 distances over the stored
+        rows in their exact order: always on one shard, and on S > 1
+        unless the fan-out merges over the bf16 wire."""
+        return self.n_shards == 1 or not resolve_wire_bf16(self.wire_bf16)
+
+    @property
     def key_list(self) -> list[str]:
         return self._keys
 

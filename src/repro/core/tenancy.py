@@ -16,7 +16,7 @@ would cost one XLA buffer (and one compiled search) per user.
     in fixed ``slab_rows``-sized slabs, each owned by exactly one tenant
     at a time. Resident tenants therefore pack into shared ``[S, R, D]``
     device blocks (one buffer for the whole pool, DESIGN.md §8) while a
-    tenant's *search* gathers only its own slabs — per-query cost scales
+    tenant's *search* gathers only its own rows — per-query cost scales
     with the tenant's corpus, not the arena.
   * **per-tenant epochs** — the pool keeps a ``mutation_epoch`` per
     tenant with exactly the per-op bump schedule a dedicated
@@ -60,7 +60,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.core.codec import VectorCodec, effective_rerank, get_codec
+from repro.core.codec import VectorCodec, get_codec
 from repro.core.flat import FlatVectorIndex, _pad_results
 from repro.core.hnsw_build import normalize_rows
 from repro.core.sharded import (INF, SHARD_AXIS, ShardedRows, place_blocks,
@@ -87,35 +87,30 @@ def split_tenant_key(nskey: str) -> tuple[str, str]:
 # ---------------------------------------------------------------------------
 # compiled tenant-scoped search (slab gather + fused top-k + tree merge)
 # ---------------------------------------------------------------------------
-def _slab_gather(blocks, gids, scl, tbl, slab_rows: int):
-    """Gather one tenant's slabs out of a shard's packed block.
+def _slab_gather(blocks, gids, scl, rows):
+    """Gather one tenant's live rows out of a shard's packed block.
 
-    blocks [RT, D] (RT = n_slabs * slab_rows), gids [RT], tbl [L] slab
-    ids (-1 padding) -> (db [L*R, D], gid [L*R], scales [L*R] | None).
-    Padding entries clip to slab 0 — which may hold ANOTHER tenant's live
-    rows — so their gathered gids are force-masked to -1 here; nothing
-    downstream may trust a gid at a padded position.
+    blocks [RT, D] (RT = n_slabs * slab_rows), gids [RT], rows [M] slot
+    positions in arena row order (-1 padding) -> (db [M, D], gid [M],
+    scales [M] | None). Padding entries clip to slot 0 — which may hold
+    ANOTHER tenant's live row — so their gathered gids are force-masked
+    to -1 here; nothing downstream may trust a gid at a padded position.
     """
-    nsl = max(blocks.shape[0] // slab_rows, 1)
-    idx = jnp.clip(tbl, 0, nsl - 1)
-    db = jnp.take(blocks.reshape(nsl, slab_rows, -1), idx,
-                  axis=0).reshape(-1, blocks.shape[-1])
-    g = jnp.take(gids.reshape(nsl, slab_rows), idx, axis=0).reshape(-1)
-    g = jnp.where(jnp.repeat(tbl >= 0, slab_rows), g, -1)
-    s = None
-    if scl is not None:
-        s = jnp.take(scl.reshape(nsl, slab_rows), idx, axis=0).reshape(-1)
+    idx = jnp.clip(rows, 0, blocks.shape[0] - 1)
+    db = jnp.take(blocks, idx, axis=0)
+    g = jnp.where(rows >= 0, jnp.take(gids, idx), -1)
+    s = None if scl is None else jnp.take(scl, idx)
     return db, g, s
 
 
-def _slab_local_topk(blocks, gids, scl, tbl, q, *, k: int, metric: str,
-                     slab_rows: int):
-    """One shard's tenant-scoped top-k: gather the tenant's slabs, run
-    the SAME fused ``flat_topk`` kernel the single-index path uses over
-    the [L*R, D] gathered db with the invalid rows (free slots inside the
-    tenant's slabs + whole padding slabs) masked in the scan, and trim to
-    the k-wide merge format."""
-    db, g, s = _slab_gather(blocks, gids, scl, tbl, slab_rows)
+def _slab_local_topk(blocks, gids, scl, tbl, q, *, k: int, metric: str):
+    """One shard's tenant-scoped top-k: gather the tenant's live rows in
+    arena row order, run the SAME fused ``flat_topk`` kernel the
+    single-index path uses over the [M, D] gathered db with the padding
+    rows masked in the scan, and trim to the k-wide merge format. The
+    scan breaks ties on position, so equal distances come back in the
+    tenant's own row order, as on a dedicated index."""
+    db, g, s = _slab_gather(blocks, gids, scl, tbl)
     d, i = ops.flat_topk(db, q, min(k, db.shape[0]), metric=metric,
                          scales=s, valid=g >= 0)
     gg = jnp.take(g, i)
@@ -126,15 +121,14 @@ def _slab_local_topk(blocks, gids, scl, tbl, q, *, k: int, metric: str,
 
 
 @functools.lru_cache(maxsize=256)
-def _slab_topk_single(k: int, metric: str, has_scales: bool,
-                      slab_rows: int):
+def _slab_topk_single(k: int, metric: str, has_scales: bool):
     """S == 1 tenant search: one fused dispatch over the gathered slabs."""
     def run(blocks, gids, scl, tbl, q):
         if metric == "cosine":
             q = q / jnp.maximum(
                 jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
         return _slab_local_topk(blocks, gids, scl, tbl, q, k=k,
-                                metric=metric, slab_rows=slab_rows)
+                                metric=metric)
 
     if has_scales:
         return jax.jit(run)
@@ -143,9 +137,8 @@ def _slab_topk_single(k: int, metric: str, has_scales: bool,
 
 
 @functools.lru_cache(maxsize=256)
-def _slab_topk_sharded(mesh, k: int, metric: str, has_scales: bool,
-                       slab_rows: int):
-    """S > 1 tenant search: per-shard slab gather + fused scan under
+def _slab_topk_sharded(mesh, k: int, metric: str, has_scales: bool):
+    """S > 1 tenant search: per-shard row gather + fused scan under
     shard_map, merged through the same ppermute tree as the single-index
     fan-out (ids exact, ties break on the smaller gid)."""
     n_shards = mesh.shape[SHARD_AXIS]
@@ -157,7 +150,7 @@ def _slab_topk_sharded(mesh, k: int, metric: str, has_scales: bool,
             q = q / jnp.maximum(
                 jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
         d, gg = _slab_local_topk(blocks, gids, scl, tbl, q, k=k,
-                                 metric=metric, slab_rows=slab_rows)
+                                 metric=metric)
         return hierarchical_topk(d, gg, k, (SHARD_AXIS,), tie_break_ids=True,
                                  axis_sizes=(n_shards,))
 
@@ -178,39 +171,30 @@ def _slab_topk_sharded(mesh, k: int, metric: str, has_scales: bool,
     return jax.jit(fn)
 
 
-def _multi_local_topk(blocks, gids, scl, tbl, q, *, k: int, metric: str,
-                      slab_rows: int):
+def _multi_local_topk(blocks, gids, scl, tbl, q, *, k: int, metric: str):
     """Cross-tenant one-dispatch search: every query row carries its OWN
-    slab table. tbl [B, L], q [B, D] -> (d [B, k], gids [B, k]).
+    row table. tbl [B, M], q [B, D] -> (d [B, k], gids [B, k]).
 
-    Per-query gather ([B, L, R, D]) + masked einsum + top_k: unlike the
+    Per-query gather ([B, M, D]) + masked einsum + top_k: unlike the
     single-tenant path this one is plain jnp, not the fused kernel.
     Rows are decoded in-graph (bf16 upcast / int8 * scale) — the same
     asymmetric-scan semantics as ``flat_topk``'s fused decode.
     """
-    nsl = max(blocks.shape[0] // slab_rows, 1)
-    d_ = blocks.shape[-1]
-    idx = jnp.clip(tbl, 0, nsl - 1)                          # [B, L]
-    rows = jnp.take(blocks.reshape(nsl, slab_rows, d_), idx,
-                    axis=0)                                  # [B, L, R, D]
-    g = jnp.take(gids.reshape(nsl, slab_rows), idx, axis=0)  # [B, L, R]
-    valid = (tbl >= 0)[:, :, None] & (g >= 0)
-    x = rows.astype(jnp.float32)
+    idx = jnp.clip(tbl, 0, blocks.shape[0] - 1)             # [B, M]
+    x = jnp.take(blocks, idx, axis=0).astype(jnp.float32)   # [B, M, D]
+    g = jnp.where(tbl >= 0, jnp.take(gids, idx), -1)        # [B, M]
     if scl is not None:
-        x = x * jnp.take(scl.reshape(nsl, slab_rows), idx,
-                         axis=0)[..., None]
+        x = x * jnp.take(scl, idx)[..., None]
     if metric == "cosine":
         q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True),
                             1e-12)
     if metric == "l2":
-        d = (jnp.sum(q * q, axis=-1)[:, None, None]
-             - 2.0 * jnp.einsum("blrd,bd->blr", x, q)
+        d = (jnp.sum(q * q, axis=-1)[:, None]
+             - 2.0 * jnp.einsum("bmd,bd->bm", x, q)
              + jnp.sum(x * x, axis=-1))
     else:
-        d = jnp.float32(1.0) - jnp.einsum("blrd,bd->blr", x, q)
-    b = tbl.shape[0]
-    d = jnp.where(valid, d, jnp.float32(INF)).reshape(b, -1)
-    g = g.reshape(b, -1)
+        d = jnp.float32(1.0) - jnp.einsum("bmd,bd->bm", x, q)
+    d = jnp.where(g >= 0, d, jnp.float32(INF))
     kk = min(k, d.shape[1])
     neg, j = jax.lax.top_k(-d, kk)
     dd = -neg
@@ -221,13 +205,12 @@ def _multi_local_topk(blocks, gids, scl, tbl, q, *, k: int, metric: str,
 
 
 @functools.lru_cache(maxsize=256)
-def _slab_topk_multi(mesh, k: int, metric: str, has_scales: bool,
-                     slab_rows: int):
+def _slab_topk_multi(mesh, k: int, metric: str, has_scales: bool):
     """Compiled cross-tenant dispatch; ``mesh`` is None for S == 1."""
     if mesh is None:
         def run(blocks, gids, scl, tbl, q):
             return _multi_local_topk(blocks, gids, scl, tbl, q, k=k,
-                                     metric=metric, slab_rows=slab_rows)
+                                     metric=metric)
         if has_scales:
             return jax.jit(run)
         return jax.jit(lambda blocks, gids, tbl, q: run(blocks, gids, None,
@@ -238,7 +221,7 @@ def _slab_topk_multi(mesh, k: int, metric: str, has_scales: bool,
         blocks, gids, tbl = blocks[0], gids[0], tbl[0]
         scl = None if scl is None else scl[0]
         d, gg = _multi_local_topk(blocks, gids, scl, tbl, q, k=k,
-                                  metric=metric, slab_rows=slab_rows)
+                                  metric=metric)
         return hierarchical_topk(d, gg, k, (SHARD_AXIS,), tie_break_ids=True,
                                  axis_sizes=(n_shards,))
 
@@ -510,11 +493,14 @@ class SlabRows(ShardedRows):
 
     # ------------------------------------------------------------- search
     def tenant_table(self, tid: str):
-        """-> (tbl [S, L] int32 slab ids (-1 pad), L, live rows). L is
-        the tenant's per-shard slab count rounded up to a power of two,
-        so the compiled search is shared across tenants of similar size
-        (the batch-bucket trick, DESIGN.md §6). Cached per
-        ``pack_epoch``."""
+        """-> (tbl [S, L*R] int32 slot positions (-1 pad), L, live rows).
+        Each shard's row lists the tenant's live slots in arena row
+        order, which is the tenant's own insertion order: a reused slot
+        does not move its new row ahead of older ones, so ties break as
+        on a dedicated index. L is the tenant's per-shard slab count
+        rounded up to a power of two, so the compiled search is shared
+        across tenants of similar size (the batch-bucket trick,
+        DESIGN.md §6). Cached per ``pack_epoch``."""
         ent = self._tables.get(tid)
         if ent is not None and ent[0] == self.pack_epoch:
             return ent[1:]
@@ -522,12 +508,15 @@ class SlabRows(ShardedRows):
         per = [self._owner_slabs[s].get(tid, []) for s in range(s_n)]
         mx = max(len(p) for p in per)
         l_pad = 1 if mx <= 1 else 1 << (mx - 1).bit_length()
-        tbl = np.full((s_n, l_pad), -1, np.int32)
+        tbl = np.full((s_n, l_pad * r), -1, np.int32)
         live = 0
         for s in range(s_n):
-            for c, j in enumerate(per[s]):
-                tbl[s, c] = j
-                live += r - len(self._slab_free[s][j])
+            slots = (np.asarray(per[s], np.int64)[:, None] * r
+                     + np.arange(r)).ravel()
+            rows = np.asarray(self._slots[s], np.int64)[slots]
+            occ = slots[rows >= 0][np.argsort(rows[rows >= 0])]
+            tbl[s, :occ.size] = occ
+            live += occ.size
         out = (tbl, l_pad, live)
         self._tables[tid] = (self.pack_epoch,) + out
         return out
@@ -539,7 +528,7 @@ class SlabRows(ShardedRows):
                     ) -> tuple[np.ndarray, np.ndarray]:
         """Exact top-k over ONE tenant's live rows -> (dists [B, k],
         arena gids [B, k], (INF, -1)-padded). One compiled dispatch; the
-        db it scans is the tenant's slabs gathered in-graph, so cost
+        db it scans is the tenant's rows gathered in-graph, so cost
         scales with the tenant, not the arena."""
         tbl, _, live = self.tenant_table(tid)
         if live == 0:
@@ -547,13 +536,11 @@ class SlabRows(ShardedRows):
         q = jnp.asarray(np.asarray(queries, np.float32))
         mesh, blocks, gids, scl = self.pack_arena()
         if mesh is None:
-            fn = _slab_topk_single(k, self.metric, scl is not None,
-                                   self.slab_rows)
+            fn = _slab_topk_single(k, self.metric, scl is not None)
             args = (blocks, gids) + (() if scl is None else (scl,)) \
                 + (jnp.asarray(tbl[0]), q)
         else:
-            fn = _slab_topk_sharded(mesh, k, self.metric, scl is not None,
-                                    self.slab_rows)
+            fn = _slab_topk_sharded(mesh, k, self.metric, scl is not None)
             args = (blocks, gids) + (() if scl is None else (scl,)) \
                 + (jnp.asarray(tbl), q)
         d, g = fn(*args)
@@ -561,13 +548,12 @@ class SlabRows(ShardedRows):
 
     def multi_topk(self, tables: np.ndarray, queries: np.ndarray, k: int
                    ) -> tuple[np.ndarray, np.ndarray]:
-        """Cross-tenant one-dispatch top-k: ``tables`` [S, B, L] carries
-        one slab table per query row (rows of DIFFERENT tenants batch
-        together when their padded L matches)."""
+        """Cross-tenant one-dispatch top-k: ``tables`` [S, B, L*R]
+        carries one row table per query row (rows of DIFFERENT tenants
+        batch together when their padded L matches)."""
         q = jnp.asarray(np.asarray(queries, np.float32))
         mesh, blocks, gids, scl = self.pack_arena()
-        fn = _slab_topk_multi(mesh, k, self.metric, scl is not None,
-                              self.slab_rows)
+        fn = _slab_topk_multi(mesh, k, self.metric, scl is not None)
         tb = jnp.asarray(tables[0] if mesh is None else tables)
         args = (blocks, gids) + (() if scl is None else (scl,)) + (tb, q)
         d, g = fn(*args)
@@ -901,19 +887,14 @@ class IndexPool:
     def query_batch(self, tid: str, queries, k: int = 10, **kw):
         """One tenant, one dispatch: [B, D] -> (keys, dists) with the
         ``VectorIndex`` shape contract (None / INF padding). Under a
-        lossy codec the slab scan is asymmetric, over-fetches
-        ``k·rerank_factor``, and reranks exactly in fp32 from the
-        canonical host rows (DESIGN.md §9)."""
-        t = self._ensure_resident(tid)
+        lossy codec the slab scan is asymmetric and its float32 top-k
+        over the stored rows is the result, as on a dedicated flat
+        index (DESIGN.md §9)."""
+        self._ensure_resident(tid)
         q = np.asarray(queries, np.float32)
         if q.ndim != 2:
             raise ValueError(f"query_batch expects [B, D], got {q.shape}")
-        rf = effective_rerank(self._codec, self.rerank_factor)
-        if rf <= 1:
-            d, rows = self._arena.tenant_topk(tid, q, k)
-        else:
-            _, cand = self._arena.tenant_topk(tid, q, k * rf)
-            d, rows = self._arena.rerank_topk(q, cand, k)
+        d, rows = self._arena.tenant_topk(tid, q, k)
         return self._rows_to_keys(rows, d, k)
 
     def query(self, tid: str, query, k: int = 10, **kw):
@@ -957,11 +938,9 @@ class IndexPool:
             return out_keys, np.stack(out_dists)
         for tid in uniq:
             self._ensure_resident(tid)
-        rf = effective_rerank(self._codec, self.rerank_factor)
-        kk = k * rf if rf > 1 else k
         b = q.shape[0]
-        out_d = np.full((b, kk), INF, np.float32)
-        out_g = np.full((b, kk), -1, np.int64)
+        out_d = np.full((b, k), INF, np.float32)
+        out_g = np.full((b, k), -1, np.int64)
         # group rows by padded slab width; empty tenants raise like a
         # dedicated empty index would
         by_l: dict[int, list[int]] = {}
@@ -974,16 +953,14 @@ class IndexPool:
             g_tenants = [tenants[i] for i in rows_idx]
             g_q = q[rows_idx]
             if len(set(g_tenants)) == 1:
-                d, g = self._arena.tenant_topk(g_tenants[0], g_q, kk)
+                d, g = self._arena.tenant_topk(g_tenants[0], g_q, k)
             else:
                 tables = np.stack(
                     [self._arena.tenant_table(tid)[0]
-                     for tid in g_tenants], axis=1)        # [S, B_g, L]
-                d, g = self._arena.multi_topk(tables, g_q, kk)
+                     for tid in g_tenants], axis=1)        # [S, B_g, L*R]
+                d, g = self._arena.multi_topk(tables, g_q, k)
             out_d[rows_idx] = d
             out_g[rows_idx] = g
-        if rf > 1:
-            out_d, out_g = self._arena.rerank_topk(q, out_g, k)
         return self._rows_to_keys(out_g, out_d, k)
 
     def _rows_to_keys(self, rows: np.ndarray, d: np.ndarray, k: int):

@@ -356,6 +356,131 @@ def test_hnsw_incremental_sync_matches_full_rebuild_int8(rng):
 
 
 # ---------------------------------------------------------------------------
+# flat under a lossy codec: the scan's own top-k, with no host re-score
+# ---------------------------------------------------------------------------
+TESTS = os.path.dirname(os.path.abspath(__file__))
+
+
+def counted_reranks(rows) -> list[int]:
+    """Wrap ``rows.rerank_topk`` -> the candidate width of each call."""
+    calls, orig = [], rows.rerank_topk
+
+    def counted(queries, gids, k):
+        calls.append(np.asarray(gids).shape[1])
+        return orig(queries, gids, k)
+
+    rows.rerank_topk = counted
+    return calls
+
+
+def flat_scan_parity(metric: str, n_shards: int) -> None:
+    """int8 flat ``query_batch`` against the composition it replaced,
+    ``rerank_exact`` over the scan's k·4 candidates: row by row the same
+    key set, distances within 1e-6 of their scale, ascending, and no
+    rerank call."""
+    data = make_corpus(300, 32, seed=0)
+    extra = make_corpus(8, 32, seed=1)
+    q = make_corpus(12, 32, seed=2)
+    idx = make_index("flat", metric=metric, dim=32, n_shards=n_shards,
+                     dtype="int8")
+    mutate(idx, data, extra)
+    calls = counted_reranks(idx._rows)
+    keys, d = idx.query_batch(q, 10)
+    assert calls == []
+    _, cand = idx._rows.topk(q, 40)
+    ref_d, ref_rows = rerank_exact(idx._rows.vectors, q, cand, 10,
+                                   metric=metric)
+    for row, ref in zip(keys, ref_rows):
+        assert set(row) == {idx._rows.key_of_row(int(r)) for r in ref}
+    d = np.asarray(d)
+    assert (np.abs(d - ref_d) <= 1e-6 * np.maximum(1.0, np.abs(ref_d))).all()
+    assert (np.diff(d, axis=1) >= 0).all()
+
+
+@pytest.mark.parametrize("n_shards", [1, 8])
+@pytest.mark.parametrize("metric", ["cosine", "ip", "l2"])
+def test_flat_lossy_scan_is_the_exact_topk(metric, n_shards):
+    if n_shards == 1:
+        flat_scan_parity(metric, n_shards)
+        return
+    run_sub(f"""
+        import sys
+        sys.path.insert(0, {TESTS!r})
+        from test_codec import flat_scan_parity
+        flat_scan_parity({metric!r}, {n_shards})
+    """)
+
+
+def test_flat_bf16_wire_keeps_the_rerank():
+    """S > 1 over the bf16 wire orders by bf16-rounded distances: only
+    there does flat int8 still over-fetch k·4 and rerank, and the rerank
+    gives back the fp32 wire's keys and float32 distances."""
+    from repro.core.sharded import ShardedRows
+
+    assert ShardedRows(n_shards=1, wire_bf16=True).exact_distances
+    assert ShardedRows(n_shards=8, wire_bf16=False).exact_distances
+    assert not ShardedRows(n_shards=8, wire_bf16=True).exact_distances
+    run_sub(f"""
+        import sys
+        import numpy as np
+        sys.path.insert(0, {TESTS!r})
+        from test_codec import counted_reranks, mutate
+        from repro.core import make_index
+        from repro.data.synthetic import make_corpus
+        idx = make_index("flat", metric="cosine", dim=32, n_shards=8,
+                         dtype="int8")
+        mutate(idx, make_corpus(300, 32, seed=0), make_corpus(8, 32, seed=1))
+        q = make_corpus(12, 32, seed=2)
+        calls = counted_reranks(idx._rows)
+        k32, d32 = idx.query_batch(q, 10)
+        assert calls == []
+        idx._rows.wire_bf16 = True
+        kbf, dbf = idx.query_batch(q, 10)
+        assert calls == [40], calls
+        assert [set(r) for r in kbf] == [set(r) for r in k32]
+        np.testing.assert_allclose(dbf, d32, rtol=0, atol=1e-6)
+    """)
+
+
+@pytest.mark.parametrize("kind,widths", [("hnsw", [20]), ("ivf", [20]),
+                                         ("pool", [])])
+def test_which_lossy_backends_rerank(kind, widths, monkeypatch):
+    """HNSW and IVF over-fetch under int8 and re-score on the host, once
+    per ``query_batch``. The tenant pool is flat search over a tenant's
+    slabs: like flat, it returns its scan's own float32 top-k, from
+    ``query_batch`` and from the cross-tenant ``query_batch_multi``."""
+    import repro.core.interface as interface
+    import repro.core.sharded as sharded
+    from repro.core.tenancy import IndexPool
+
+    calls = []
+
+    def counted(vectors, queries, ids, k, *, metric):
+        calls.append(np.asarray(ids).shape[1])
+        return rerank_exact(vectors, queries, ids, k, metric=metric)
+
+    monkeypatch.setattr(interface, "rerank_exact", counted)
+    monkeypatch.setattr(sharded, "rerank_exact", counted)
+    data = make_corpus(200, 32, seed=0)
+    keys = [f"d{i}" for i in range(200)]
+    q = make_corpus(4, 32, seed=2)
+    if kind == "pool":
+        pool = IndexPool(dim=32, dtype="int8")
+        pool.bulk_insert("a", keys[:100], data[:100])
+        pool.bulk_insert("b", keys[100:], data[100:])
+        out, _ = pool.query_batch("a", q, 5)
+        multi, _ = pool.query_batch_multi(q, ["a", "b", "a", "b"], 5)
+        assert all(len(row) == 5 for row in multi)
+    else:
+        cfg = dict(BACKENDS)[kind]
+        idx = make_index(kind, metric="cosine", dtype="int8", **cfg)
+        idx.bulk_insert(keys, data)
+        out, _ = idx.query_batch(q, 5)
+    assert calls == widths
+    assert all(len(row) == 5 for row in out)
+
+
+# ---------------------------------------------------------------------------
 # sharded codec paths (multi-device mesh via subprocess)
 # ---------------------------------------------------------------------------
 def test_sharded_codec_parity_bitforbit():

@@ -2,7 +2,8 @@
 profiler trace of RetrievalEngine ticks holds one ``engine.coalesce``
 per tick and the index spans once per ``query_batch``, each inside its
 ``engine.dispatch``, under the exact names of ``SPANS``; with no
-profiler running the results are the same."""
+profiler running the results are the same. Flat int8 returns the scan's
+own float32 top-k, so only HNSW writes ``index.rerank``."""
 import dataclasses
 import glob
 import os
@@ -22,7 +23,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from bench.devtrace import HOST_SPANS  # noqa: E402
 
 DIM, ROWS, MAX_BATCH = 16, 300, 8
-INDEX_SPANS = ("index.scan", "index.fetch", "index.rerank", "index.keys")
+# the index spans of one query_batch, in order, per kind
+INDEX_SPANS = {"flat": ("index.scan", "index.fetch", "index.keys"),
+               "hnsw": ("index.scan", "index.fetch", "index.rerank",
+                        "index.keys")}
 
 
 @dataclasses.dataclass
@@ -107,7 +111,10 @@ def test_spans_nest_once_per_tick_and_call(kind, tmp_path):
     assert len(disp) == calls == n_ticks * 3
     # one fan-out per dispatch, and one for the tick with a repeat
     assert len(tr.spans("engine.fanout")) == len(disp) + 1
-    for name in INDEX_SPANS:
+    for name in dispatch.SPANS:
+        if name.startswith("index.") and name not in INDEX_SPANS[kind]:
+            assert tr.spans(name) == [], name
+    for name in INDEX_SPANS[kind]:
         spans = tr.spans(name)
         assert len(spans) == calls, name
         for s in spans:
@@ -116,10 +123,10 @@ def test_spans_nest_once_per_tick_and_call(kind, tmp_path):
             assert len(inside) == 1, name
     # within a call the index spans run in order and do not overlap
     for d in disp:
-        inner = sorted((s for n in INDEX_SPANS for s in tr.spans(n)
+        inner = sorted((s for n in INDEX_SPANS[kind] for s in tr.spans(n)
                         if d.start <= s.start and s.end <= d.end),
                        key=lambda s: s.start)
-        assert [s.name for s in inner] == list(INDEX_SPANS)
+        assert [s.name for s in inner] == list(INDEX_SPANS[kind])
         assert all(a.end <= b.start for a, b in zip(inner, inner[1:]))
 
 
@@ -148,10 +155,14 @@ def test_span_names_are_the_programs_own():
 @pytest.mark.parametrize("kind", ["flat", "hnsw"])
 def test_the_trace_holds_no_span_outside_spans(kind, tmp_path):
     """Every name the program writes is listed in ``SPANS``, unchanged
-    by the args it carries, and the listed names all appear."""
+    by the args it carries, and the listed names all appear but the
+    index spans this kind does not write."""
     idx, data = build(kind)
     q = queries(data, 16, seed=3)
     _, tr, _ = traced(idx, q, 16, str(tmp_path))
     written = {e.name for e in tr.events
                if e.name.startswith(("engine.", "index."))}
-    assert written == set(dispatch.SPANS)
+    assert written == {n for n in dispatch.SPANS
+                       if not n.startswith("index.")} | set(INDEX_SPANS[kind])
+    assert set().union(*INDEX_SPANS.values()) == {
+        n for n in dispatch.SPANS if n.startswith("index.")}

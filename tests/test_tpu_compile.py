@@ -116,8 +116,11 @@ def _flash(s):
 
 CASES = {
     "distance_topk_f32": _topk(jnp.float32),
+    # the benchmark's flat cell: int8 flat search returns the scan's own
+    # top-10, with no over-fetch
     "distance_topk_int8": _topk(jnp.int8),
-    # the benchmark's flat cell: k=10 times the int8 rerank factor of 4
+    # the one over-fetching caller of the scan, flat over the bf16 wire:
+    # k=10 times the int8 rerank factor of 4
     "distance_topk_int8_k40": _topk(jnp.int8, k=40),
     "gather_distance": _gather,
     "beam_b1_ef64": _beam(1, 64),
